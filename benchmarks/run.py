@@ -61,6 +61,8 @@ def main(argv=None) -> None:
         unknown = sorted(set(only) - set(names))
         if unknown:
             ap.error(f"unknown benchmark(s) {unknown}; choose from {names}")
+    from repro import compile_cache
+    compile_cache.enable()
     print("name,seconds,status")
     failures = 0
     for name, fn in jobs:

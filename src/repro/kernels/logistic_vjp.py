@@ -19,7 +19,8 @@ Two margin losses share the one kernel body (static ``loss`` switch):
 
 TPU adaptation (DESIGN.md §7): the paper's CSR-sparse rows (p=0.001) become
 dense VMEM tiles — gather/scatter on the sparse structure would idle the MXU;
-dense row tiles of the d<=~12k feature dim fit VMEM comfortably.
+the row tile shrinks with the feature dim so a tile fits VMEM
+(``block_rows_for``).
 
 Padding contract (handled by ops.fused_*_vjp): rows are padded with
 mask=0 (excluded from loss and grad), the feature dim with zero columns.
@@ -34,8 +35,32 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# Row-tile height. 256 rows x 10112 padded features x 4B = ~10.4 MB VMEM.
+# Row-tile height for narrow shards; ``block_rows_for`` shrinks it for wide
+# ones.
 DEFAULT_BLOCK_ROWS = 256
+# Bytes one f32 A tile may take.  The pipeline double-buffers it, and the
+# chip compiler's default scoped-VMEM limit is 16 MiB: a 256 x 10112 tile
+# (10.4 MB, ~20 MB double-buffered) is refused at the paper's width, a
+# 128 x 10112 one compiles.  The rest of the limit holds the (TN, 1) b and
+# mask tiles, x and the gradient accumulator.
+A_TILE_BYTES = 6 * 2**20
+
+
+def block_rows_for(d_padded: int) -> int:
+    """Row tile for a lane-padded feature width: ``DEFAULT_BLOCK_ROWS``
+    halved until the f32 A tile fits ``A_TILE_BYTES``, but never below
+    the f32 sublane multiple (8).  256 rows up to D=6144, 128 up to
+    D=12288."""
+    br = DEFAULT_BLOCK_ROWS
+    while br > 8 and br * d_padded * 4 > A_TILE_BYTES:
+        br //= 2
+    return br
+
+
+# Both matvecs multiply in full f32 on the MXU: the gather path the kernel
+# is checked against multiplies in f32, and the kernel is bound by HBM
+# traffic (2 flops per 4-byte element of A), so the extra passes are free.
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _margin_kernel(loss: str, gamma: float,
@@ -49,6 +74,7 @@ def _margin_kernel(loss: str, gamma: float,
 
     # signed activation <a_n, x>   (MXU: (TN,D) @ (D,1))
     ax = jax.lax.dot_general(a, x.T, (((1,), (0,)), ((), ())),
+                             precision=_PRECISION,
                              preferred_element_type=jnp.float32)  # (TN,1)
     if loss == "logistic":
         # l(m) = log1p(exp(-m)) at m = b*ax; stable via logaddexp
@@ -73,6 +99,7 @@ def _margin_kernel(loss: str, gamma: float,
     c = mask * dldax                                 # (TN,1)
     # gradient partial: A^T c  (MXU: (1,TN) @ (TN,D))
     gpart = jax.lax.dot_general(c.T, a, (((1,), (0,)), ((), ())),
+                                precision=_PRECISION,
                                 preferred_element_type=jnp.float32)  # (1,D)
 
     @pl.when(i == 0)

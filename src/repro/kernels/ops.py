@@ -50,20 +50,39 @@ def _round_up(n: int, m: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("loss", "gamma", "block_rows", "mode"))
-def _margin_impl(A, b, x, mask, *, loss, gamma, block_rows, mode):
-    N, D = A.shape
-    # small shards tile to the f32 sublane multiple (8) rather than the
-    # full default row tile — a W=1024 fleet of 8-row lanes must not pad
-    # every lane to 256 rows
-    br = min(block_rows, _round_up(N, 8))
-    Np = _round_up(N, br)
-    Dp = _round_up(D, 128)
-    a_p = jnp.zeros((Np, Dp), jnp.float32).at[:N, :D].set(A)
-    b_p = jnp.zeros((Np, 1), jnp.float32).at[:N, 0].set(b)
-    m_p = jnp.zeros((Np, 1), jnp.float32).at[:N, 0].set(mask)
-    x_p = jnp.zeros((1, Dp), jnp.float32).at[0, :D].set(x)
+def margin_layout(n_rows: int, n_features: int):
+    """(padded rows, padded features, row tile) in which the margin
+    kernels stream an (n_rows, n_features) shard.
+
+    Features pad to the 128-lane multiple.  Small shards tile to the f32
+    sublane multiple (8) rather than the full default row tile — a W=1024
+    fleet of 8-row lanes must not pad every lane to 256 rows — and wide
+    shards take the row tile that fits VMEM (``block_rows_for``).  A
+    shard already in this layout maps to itself."""
+    dp = _round_up(n_features, 128)
+    br = min(lv_k.block_rows_for(dp), _round_up(n_rows, 8))
+    return _round_up(n_rows, br), dp, br
+
+
+def _pad_f32(a, shape):
+    """Zero-pad a 2-D array to ``shape`` as f32; one already of that
+    shape is not copied."""
+    a = a.astype(jnp.float32)
+    if a.shape == shape:
+        return a
+    return jnp.zeros(shape, jnp.float32).at[:a.shape[0], :a.shape[1]].set(a)
+
+
+@functools.partial(jax.jit, static_argnames=("loss", "gamma", "mode"))
+def _margin_impl(A, b, x, mask, *, loss, gamma, mode):
+    D = x.shape[0]
+    Np, Dp, br = margin_layout(*A.shape)
+    # a shard staged in the kernel layout (see margin_layout) streams as
+    # is: padding it here would copy A on every FISTA iteration
+    a_p = _pad_f32(A, (Np, Dp))
+    b_p = _pad_f32(b[:, None], (Np, 1))
+    m_p = _pad_f32(mask[:, None], (Np, 1))
+    x_p = _pad_f32(x[None, :], (1, Dp))
     if mode == "ref":
         if loss == "logistic":
             f, grad = ref.logistic_vjp_ref(a_p, b_p, m_p, x_p)
@@ -77,13 +96,12 @@ def _margin_impl(A, b, x, mask, *, loss, gamma, block_rows, mode):
     return f[0, 0], grad[0, :D]
 
 
-def _margin_dispatch(A, b, x, mask, *, loss, gamma, block_rows):
+def _margin_dispatch(A, b, x, mask, *, loss, gamma):
     """Shared entry: accepts a leading worker axis (A (W,N,D), b/mask
     (W,N), x (W,D)) and per-lane row masks; ``jax.vmap`` lifts the batch
     onto the Pallas grid, so all W lanes run in ONE kernel launch."""
     mode = _mode()
-    one = functools.partial(_margin_impl, loss=loss, gamma=gamma,
-                            block_rows=block_rows, mode=mode)
+    one = functools.partial(_margin_impl, loss=loss, gamma=gamma, mode=mode)
     if A.ndim == 3:
         if mask is None:
             mask = jnp.ones(A.shape[:2], jnp.float32)
@@ -93,23 +111,21 @@ def _margin_dispatch(A, b, x, mask, *, loss, gamma, block_rows):
     return one(A, b, x, mask)
 
 
-def fused_logistic_vjp(A, b, x, *, mask=None,
-                       block_rows: int = lv_k.DEFAULT_BLOCK_ROWS):
+def fused_logistic_vjp(A, b, x, *, mask=None):
     """Single-pass loss+grad of sum_n mask_n * log1p(exp(-b_n <a_n, x>)).
 
     A (N, D) f32, b (N,) ±1, x (D,); ``mask`` an optional {0,1} row mask
     (padded rows contribute exactly zero).  A leading worker axis batches:
-    A (W, N, D), b/mask (W, N), x (W, D) -> (loss (W,), grad (W, D))."""
-    return _margin_dispatch(A, b, x, mask, loss="logistic", gamma=0.0,
-                            block_rows=block_rows)
+    A (W, N, D), b/mask (W, N), x (W, D) -> (loss (W,), grad (W, D)).
+    A may come staged in ``margin_layout(N, D)``: rows already padded
+    (with mask 0) and zero columns past ``len(x)``."""
+    return _margin_dispatch(A, b, x, mask, loss="logistic", gamma=0.0)
 
 
-def fused_svm_vjp(A, b, x, *, gamma: float, mask=None,
-                  block_rows: int = lv_k.DEFAULT_BLOCK_ROWS):
+def fused_svm_vjp(A, b, x, *, gamma: float, mask=None):
     """Smoothed-hinge twin of ``fused_logistic_vjp`` (problems/svm.py's
     loss; ``gamma`` the smoothing width).  Same shapes/batching/masking."""
-    return _margin_dispatch(A, b, x, mask, loss="hinge", gamma=float(gamma),
-                            block_rows=block_rows)
+    return _margin_dispatch(A, b, x, mask, loss="hinge", gamma=float(gamma))
 
 
 def logistic_value_and_grad(A, b):

@@ -164,6 +164,28 @@ def densify_sparse_rows(idx, vals, d: int) -> np.ndarray:
     return a
 
 
+def margin_kernel_batch(batch, mask, d: int):
+    """Stacked gather-format shards ((idx, vals, b) (W, N, ...), mask
+    (W, N)) -> the dense batch the margin kernels stream: ((A, b), mask)
+    with A (W, Np, Dp) already in ``ops.margin_layout(N, d)`` and b, mask
+    zero on the padded rows.  Staged once per fleet size: a shard the
+    kernel wrapper had to pad would be copied on every FISTA iteration
+    (at the paper's width, three copies of the whole batch in HBM)."""
+    from repro.kernels import ops
+    idx, vals, b = batch
+    idx, vals = np.asarray(idx), np.asarray(vals)
+    W, n, _ = idx.shape
+    n_p, d_p, _ = ops.margin_layout(n, d)
+    dense = np.zeros((W, n_p, d_p), vals.dtype)
+    for w in range(W):
+        dense[w, :n, :d] = densify_sparse_rows(idx[w], vals[w], d)
+
+    def pad_rows(a):
+        return jnp.pad(a, ((0, 0), (0, n_p - n)))
+
+    return (jnp.asarray(dense), pad_rows(b)), pad_rows(mask)
+
+
 # ---------------------------------------------------------------------------
 # Batched execution: all W subproblems in one XLA call
 # ---------------------------------------------------------------------------

@@ -9,7 +9,6 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.fista import FistaOptions
 from repro.problems import base
@@ -133,17 +132,14 @@ class LogRegProblem(base.BatchedShardProblem):
     def kernel_batch_shards(self, n_workers: int):
         """Dense twin of ``batch_shards``: the Pallas margin kernel
         streams dense MXU row tiles, so the sparse gather-format shards
-        are scattered into (W, Nmax, d) rows once per fleet size (cached;
-        ``rescale()`` to a new W re-densifies from the stacked batch)."""
+        are scattered into the kernel's padded layout once per fleet size
+        (``base.margin_kernel_batch``; cached, ``rescale()`` to a new W
+        re-densifies from the stacked batch)."""
         if self._kernel_batch_cache is None:
             self._kernel_batch_cache = {}
         if n_workers not in self._kernel_batch_cache:
-            (idx, vals, b), mask = self.batch_shards(n_workers)
-            d = self.cfg.n_features
-            dense = np.stack([base.densify_sparse_rows(idx[w], vals[w], d)
-                              for w in range(n_workers)])
-            self._kernel_batch_cache[n_workers] = (
-                (jnp.asarray(dense, self.dtype), b), mask)
+            self._kernel_batch_cache[n_workers] = base.margin_kernel_batch(
+                *self.batch_shards(n_workers), self.cfg.n_features)
         return self._kernel_batch_cache[n_workers]
 
     def _masked_kernel_loss_value_and_grad(self, shard, mask):

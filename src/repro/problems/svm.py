@@ -22,7 +22,6 @@ stall plain gradient steps; FISTA's momentum + backtracking handle it).
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.logreg_paper import scaled
 from repro.core import prox
@@ -106,12 +105,8 @@ class SVMProblem(base.FistaShardProblem):
         if self._kernel_batch_cache is None:
             self._kernel_batch_cache = {}
         if n_workers not in self._kernel_batch_cache:
-            (idx, vals, b), mask = self.batch_shards(n_workers)
-            d = self.n_features
-            dense = np.stack([base.densify_sparse_rows(idx[w], vals[w], d)
-                              for w in range(n_workers)])
-            self._kernel_batch_cache[n_workers] = (
-                (jnp.asarray(dense, self.dtype), b), mask)
+            self._kernel_batch_cache[n_workers] = base.margin_kernel_batch(
+                *self.batch_shards(n_workers), self.n_features)
         return self._kernel_batch_cache[n_workers]
 
     def _masked_kernel_loss_value_and_grad(self, shard, mask):

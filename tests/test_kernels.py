@@ -65,6 +65,59 @@ def test_ops_wrapper_matches_data_oracle(rng, monkeypatch):
     np.testing.assert_allclose(g_k, g_r, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("n, d, layout", [
+    (9375, 10_000, (9472, 10112, 128)),    # the paper's W=64 shard
+    (1500, 10_000, (1536, 10112, 128)),
+    (300, 6144, (512, 6144, 256)),         # widest D for 256-row tiles
+    (300, 6145, (384, 6272, 128)),
+    (300, 128, (512, 128, 256)),
+    (13, 100, (16, 128, 16)),              # small shards tile to 8 rows
+    (9472, 10112, (9472, 10112, 128)),     # a staged shard maps to itself
+])
+def test_margin_layout(n, d, layout):
+    from repro.kernels import ops
+    assert ops.margin_layout(n, d) == layout
+    assert layout[2] * layout[1] * 4 <= lv_k.A_TILE_BYTES
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_ops_wrapper_takes_staged_layout(rng, monkeypatch, mode):
+    """A batch staged by problems.base.margin_kernel_batch (rows padded
+    with mask 0, zero columns past len(x)) gives exactly what the
+    unstaged shards give."""
+    monkeypatch.setenv("REPRO_PALLAS", mode)
+    from repro.kernels import ops
+    from repro.problems import base as pbase
+    W, n, k, d = 3, 37, 4, 150
+    idx = rng.randint(0, d, (W, n, k)).astype(np.int32)
+    vals = rng.randn(W, n, k).astype(np.float32)
+    b = jnp.asarray(np.sign(rng.randn(W, n)), jnp.float32)
+    mask = jnp.asarray((rng.rand(W, n) < 0.8).astype(np.float32))
+    x = _rand(rng, (W, d), scale=0.1)
+    (A_s, b_s), m_s = pbase.margin_kernel_batch((idx, vals, b), mask, d)
+    assert A_s.shape == (W,) + ops.margin_layout(n, d)[:2]
+    A = jnp.asarray(np.stack([pbase.densify_sparse_rows(idx[w], vals[w], d)
+                              for w in range(W)]))
+    f, g = ops.fused_logistic_vjp(A, b, x, mask=mask)
+    f_s, g_s = ops.fused_logistic_vjp(A_s, b_s, x, mask=m_s)
+    assert g_s.shape == (W, d)
+    np.testing.assert_array_equal(np.asarray(f_s), np.asarray(f))
+    np.testing.assert_array_equal(np.asarray(g_s), np.asarray(g))
+
+
+@pytest.mark.parametrize("name", ["logreg", "svm"])
+def test_kernel_batch_is_staged(name):
+    """The problems hand the kernel path its batch already in the kernel
+    layout, so the wrapper never pads A inside the FISTA loop."""
+    from repro import problems
+    from repro.kernels import ops
+    p = problems.make(name, n_samples=61, n_features=130)
+    (A, b), mask = p.kernel_batch_shards(4)
+    n_p, d_p, _ = ops.margin_layout(16, 130)          # 4 lanes of <= 16 rows
+    assert A.shape == (4, n_p, d_p) and b.shape == mask.shape == (4, n_p)
+    assert float(jnp.sum(mask)) == 61.0
+
+
 # ---------------------------------------------------------------------------
 # REPRO_PALLAS dispatch
 # ---------------------------------------------------------------------------

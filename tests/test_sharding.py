@@ -17,8 +17,8 @@ from repro.models import model as M
 from repro.optim import optimizers as opt_mod
 from repro.parallel import sharding
 
-POD = AbstractMesh((("data", 16), ("model", 16)))
-MULTI = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+POD = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(spec_tree, shape_tree, mesh, where=""):
