@@ -1,38 +1,14 @@
-"""Kernel-level benchmark: modeled TPU roofline per Pallas kernel + CPU
-oracle timing (the container has no TPU; the kernels compile for TPU and
-are validated in interpret mode by tests/test_kernels.py).
-
-For each kernel: FLOPs, HBM bytes, arithmetic intensity, and the v5e
-roofline-implied time at production shapes — plus the fused-vs-unfused
-traffic ratio the fusion buys (e.g. logistic_vjp streams A once, not twice).
-
-Also runs the ENGINE comparison: the batched scheduler at fleet scale
-(W in {64, 256, 1024}) with kernel="xla" vs kernel="pallas" (the fused
+"""Kernel-engine benchmark: the batched scheduler at fleet scale (W in
+{64, 256, 1024}) with kernel="xla" vs kernel="pallas" (the fused
 wrappers run their deterministic jnp oracle on CPU — same padded
 layout/masking as the TPU kernels), per-cell round time + residual.  The
 residuals are deterministic simulator metrics and are pinned by
-benchmarks/check_regression.py under "engine_compare".
+benchmarks/check_regression.py under "engine_compare".  What the kernels
+cost on the chip is measured by ``benchmarks/chip`` (``solve_roofline``).
 """
 import time
 
-import numpy as np
-
-from benchmarks.common import emit, timed
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
-
-
-def _roofline(name, flops, bytes_, note=""):
-    t_c = flops / PEAK_FLOPS_BF16
-    t_m = bytes_ / HBM_BW
-    ai = flops / bytes_
-    bound = "compute" if t_c > t_m else "memory"
-    row = {"flops": flops, "bytes": bytes_, "intensity": ai,
-           "t_roofline_us": max(t_c, t_m) * 1e6, "bound": bound,
-           "note": note}
-    print(f"  {name:18s} {flops/1e9:9.2f} GF {bytes_/1e6:9.1f} MB "
-          f"AI={ai:7.1f} t={row['t_roofline_us']:8.1f}us {bound}-bound "
-          f"{note}")
-    return row
+from benchmarks.common import emit
 
 
 def engine_compare(ws=(64, 256, 1024), rounds=3) -> dict:
@@ -82,50 +58,7 @@ def engine_compare(ws=(64, 256, 1024), rounds=3) -> dict:
 
 
 def main():
-    rows = {}
-    # logistic_vjp at the paper's worker shard: N_w=9375 (W=64), d=10k
-    N, D = 9472, 10112                      # padded to tile multiples
-    flops = 2 * 2 * N * D                   # fwd matvec + grad matvec
-    bytes_once = (N * D + N * 2 + D) * 4    # A streamed ONCE (fused)
-    bytes_twice = (2 * N * D + N * 2 + D) * 4
-    rows["logistic_vjp"] = _roofline(
-        "logistic_vjp", flops, bytes_once,
-        f"fusion halves traffic: {bytes_twice/bytes_once:.2f}x")
-
-    # soft_threshold z-update at d=10k: one pass, 3 outputs
-    D = 10112
-    rows["soft_threshold"] = _roofline(
-        "soft_threshold", 5 * D, 3 * D * 4,
-        "elementwise; fuses z-update + ||dz||^2 + nnz")
-
-    # flash attention, qwen2.5 prefill tile: B=1 KV-group, S=32k, hd=128
-    S, hd, G = 32768, 128, 5
-    flops = 2 * 2 * (S * S // 2) * hd * G   # causal half, qk + pv
-    bytes_ = (2 * S * hd * G + 2 * S * hd) * 2
-    rows["flash_attention"] = _roofline("flash_attention", flops, bytes_,
-                                        "causal 32k, GQA 5:1")
-
-    # decode attention: B=8 local, 32k cache, KV=8, hd=128
-    B, S, KV, hd, G = 8, 32768, 8, 128, 5
-    flops = 2 * 2 * B * KV * G * S * hd
-    bytes_ = 2 * B * S * KV * hd * 2
-    rows["decode_attention"] = _roofline("decode_attention", flops, bytes_,
-                                         "cache-bandwidth bound (expected)")
-
-    # CPU wall time of the jnp oracle paths (sanity only)
-    import jax, jax.numpy as jnp
-    from repro.kernels import ops
-    A = jnp.ones((1024, 512), jnp.float32)
-    b = jnp.ones((1024,), jnp.float32)
-    x = jnp.ones((512,), jnp.float32)
-    _, t = timed(lambda: jax.block_until_ready(
-        ops.fused_logistic_vjp(A, b, x)))
-    rows["cpu_oracle_logistic_us"] = t * 1e6
-    print(f"  cpu oracle logistic_vjp: {t*1e6:.0f} us/call (1024x512)")
-
-    rows["engine_compare"] = engine_compare()
-
-    emit("bench_kernels", rows)
+    emit("bench_kernels", {"engine_compare": engine_compare()})
 
 
 if __name__ == "__main__":

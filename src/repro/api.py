@@ -34,6 +34,7 @@ import numpy as np
 from repro import problems
 from repro.runtime.cluster import (Cluster, ClusterConfig, ClusterResult,
                                    DagRun, DagSpec, StageResult, StageSpec)
+from repro.runtime import spans
 from repro.runtime.scheduler import (RoundMetrics, Scheduler,
                                      SchedulerConfig)
 
@@ -140,9 +141,11 @@ def build(spec: ExperimentSpec, *, problem=None):
     the escape hatch for drivers that need mid-run control (manual
     ``rescale``, checkpoint surgery).  Pass ``problem`` to reuse an
     existing instance (its shard/solver caches) across runs."""
-    if problem is None:
-        problem = problems.make(spec.problem, **dict(spec.problem_kwargs))
-    return problem, Scheduler(problem, spec.scheduler)
+    with spans.span("build"):
+        if problem is None:
+            problem = problems.make(spec.problem,
+                                    **dict(spec.problem_kwargs))
+        return problem, Scheduler(problem, spec.scheduler)
 
 
 def result_from_scheduler(spec: ExperimentSpec, problem, sched: Scheduler,

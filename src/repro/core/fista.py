@@ -40,10 +40,12 @@ class FistaState(NamedTuple):
     g_norm: jnp.ndarray           # ||grad F(y)|| of last step
     rel_impr: jnp.ndarray         # last relative improvement
     k: jnp.ndarray                # iteration counter
+    n_ls: jnp.ndarray             # line-search trials over all iterations
 
 
 def _backtrack(vg: Callable, y, f_y, g_y, lip, opts: FistaOptions):
-    """Find L (by eta-doubling) with F(y - g/L) <= F(y) - ||g||^2/(2L)."""
+    """Find L (by eta-doubling) with F(y - g/L) <= F(y) - ||g||^2/(2L).
+    Returns (L, the number of trials made, each one pass of F)."""
     gsq = jnp.vdot(g_y, g_y).real
 
     def cond(carry):
@@ -58,8 +60,8 @@ def _backtrack(vg: Callable, y, f_y, g_y, lip, opts: FistaOptions):
         lip_next = jnp.where(ok, lip, lip * opts.eta)
         return (lip_next, j + 1, ok)
 
-    lip, _, _ = jax.lax.while_loop(cond, body, (lip, jnp.int32(0), jnp.asarray(False)))
-    return lip
+    lip, j, _ = jax.lax.while_loop(cond, body, (lip, jnp.int32(0), jnp.asarray(False)))
+    return lip, j
 
 
 def fista(
@@ -73,7 +75,8 @@ def fista(
     init = FistaState(
         x=x0, y=x0, t=jnp.asarray(1.0, ft), lip=jnp.asarray(opts.l0, ft),
         f_x=f0, g_norm=jnp.asarray(jnp.inf, ft),
-        rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0))
+        rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0),
+        n_ls=jnp.int32(0))
 
     def cond(st: FistaState):
         not_min = st.k < opts.min_iters
@@ -86,7 +89,7 @@ def fista(
 
     def body(st: FistaState):
         f_y, g_y = value_and_grad(st.y)
-        lip = _backtrack(value_and_grad, st.y, f_y, g_y, st.lip, opts)
+        lip, n_try = _backtrack(value_and_grad, st.y, f_y, g_y, st.lip, opts)
         x_new = st.y - g_y / lip
         f_new, _ = value_and_grad(x_new)
         # monotone safeguard (MFISTA-lite): never accept an increase over x_k
@@ -98,7 +101,8 @@ def fista(
         rel = (st.f_x - f_new) / jnp.maximum(jnp.abs(st.f_x), 1e-30)
         return FistaState(
             x=x_new, y=y_new, t=t_new, lip=lip, f_x=f_new,
-            g_norm=jnp.linalg.norm(g_y), rel_impr=rel, k=st.k + 1)
+            g_norm=jnp.linalg.norm(g_y), rel_impr=rel, k=st.k + 1,
+            n_ls=st.n_ls + n_try)
 
     final = jax.lax.while_loop(cond, body, init)
     return final.x, final
@@ -109,7 +113,7 @@ def fista_fixed(value_and_grad, x0, n_iters: int, opts: FistaOptions = FistaOpti
     needed (e.g. inside vmapped workers during the dry-run)."""
     def body(st: FistaState, _):
         f_y, g_y = value_and_grad(st.y)
-        lip = _backtrack(value_and_grad, st.y, f_y, g_y, st.lip, opts)
+        lip, n_try = _backtrack(value_and_grad, st.y, f_y, g_y, st.lip, opts)
         x_new = st.y - g_y / lip
         f_new, _ = value_and_grad(x_new)
         worse = f_new > st.f_x
@@ -120,13 +124,14 @@ def fista_fixed(value_and_grad, x0, n_iters: int, opts: FistaOptions = FistaOpti
         rel = (st.f_x - f_new) / jnp.maximum(jnp.abs(st.f_x), 1e-30)
         return FistaState(x=x_new, y=y_new, t=t_new, lip=lip, f_x=f_new,
                           g_norm=jnp.linalg.norm(g_y), rel_impr=rel,
-                          k=st.k + 1), None
+                          k=st.k + 1, n_ls=st.n_ls + n_try), None
 
     f0, _ = value_and_grad(x0)
     ft = f0.dtype
     init = FistaState(x=x0, y=x0, t=jnp.asarray(1.0, ft),
                       lip=jnp.asarray(opts.l0, ft), f_x=f0,
                       g_norm=jnp.asarray(jnp.inf, ft),
-                      rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0))
+                      rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0),
+                      n_ls=jnp.int32(0))
     final, _ = jax.lax.scan(body, init, None, length=n_iters)
     return final.x, final

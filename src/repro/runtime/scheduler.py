@@ -73,6 +73,7 @@ from repro.runtime.autoscale import AutoscaleConfig, Autoscaler
 from repro.runtime.billing import BillingConfig, BillingMeter
 from repro.runtime.pool import LambdaPool, PoolConfig
 from repro.runtime.reduce import TreeConfig, fanin_drain
+from repro.runtime import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,6 +160,12 @@ class RoundMetrics(NamedTuple):
     # for free by the fused z-update (kernel="pallas" on l1 workloads);
     # -1 when the jnp z-update ran (it does not compute sparsity)
     z_nnz: int = -1
+    # seconds per host span of the round (runtime.spans), filled until
+    # Scheduler.step returns; None when the round ran outside step()
+    span_s: Optional[Dict[str, float]] = None
+    # (W,) line-search trials per worker over its FISTA iterations; None
+    # where no solve recorded them (the loop engine, direct solvers)
+    ls_trials: Optional[np.ndarray] = None
 
 
 class Scheduler:
@@ -372,27 +379,31 @@ class Scheduler:
         W = self.cfg.n_workers
         WL = self.n_logical
         extras = np.zeros(W)
-        for wid in range(W):
-            extras[wid] = self._maybe_respawn(wid)
-        r = self.x - self.z[None, :]
-        u_new = self.u + r
-        q = np.asarray(jnp.einsum("wd,wd->w", r, r), np.float64)
-        # the kernel kwarg is only passed on the pallas path, so
-        # third-party solve_all overrides with the pre-kernel signature
-        # keep working under the default config
-        if self._kernel_pallas:
-            xs_new, iters = self.problem.solve_all(self.x, u_new, self.z,
-                                                   self.rho, kernel="pallas")
-        else:
-            xs_new, iters = self.problem.solve_all(self.x, u_new, self.z,
-                                                   self.rho)
-        omegas = xs_new + u_new
-        if self.codec.method != "none":
-            # the codec is stateful per logical slot (delta error
-            # feedback), so compression keeps a per-slot encode loop —
-            # the solve batching still amortizes the W device dispatches
-            omegas = jnp.stack([self.codec.encode(lw, omegas[lw])
-                                for lw in range(WL)])
+        with spans.span("round.respawn"):
+            for wid in range(W):
+                extras[wid] = self._maybe_respawn(wid)
+        with spans.span("round.solve"):
+            r = self.x - self.z[None, :]
+            u_new = self.u + r
+            q = jnp.einsum("wd,wd->w", r, r)
+            with spans.span("round.q.wait"):
+                q = np.asarray(q, np.float64)
+            # the kernel kwarg is only passed on the pallas path, so
+            # third-party solve_all overrides with the pre-kernel
+            # signature keep working under the default config
+            if self._kernel_pallas:
+                xs_new, iters = self.problem.solve_all(
+                    self.x, u_new, self.z, self.rho, kernel="pallas")
+            else:
+                xs_new, iters = self.problem.solve_all(self.x, u_new,
+                                                       self.z, self.rho)
+            omegas = xs_new + u_new
+            if self.codec.method != "none":
+                # the codec is stateful per logical slot (delta error
+                # feedback), so compression keeps a per-slot encode loop
+                # — the solve batching still amortizes the W dispatches
+                omegas = jnp.stack([self.codec.encode(lw, omegas[lw])
+                                    for lw in range(WL)])
         self._batched_xu = (xs_new, u_new)
         return q, np.asarray(iters, np.int64), omegas, extras
 
@@ -414,18 +425,24 @@ class Scheduler:
             from repro.kernels import ops
             thr = float(lam) / (n_eff * self.rho)
             z_new, ssq, nnz = ops.fused_z_update(omega_bar, self.z, thr)
-            s_norm = float(self.rho * np.sqrt(float(ssq)) * np.sqrt(n_eff))
-            self._z_nnz = int(nnz)
+            with spans.span("round.master.wait"):
+                ssq, nnz = float(ssq), int(nnz)
+            s_norm = float(self.rho * np.sqrt(ssq) * np.sqrt(n_eff))
+            self._z_nnz = nnz
         else:
             z_new = self.problem.prox_h(omega_bar, 1.0 / (n_eff * self.rho))
-            s_norm = float(self.rho * jnp.linalg.norm(z_new - self.z)
-                           * np.sqrt(n_eff))
+            s_norm = (self.rho * jnp.linalg.norm(z_new - self.z)
+                      * np.sqrt(n_eff))
+            with spans.span("round.master.wait"):
+                s_norm = float(s_norm)
             self._z_nnz = -1
         self.z_prev, self.z = self.z, z_new
         rho_old = self.rho
         if adapt_rho:
-            self.rho = float(admm.new_penalty(
-                jnp.float32(self.rho), r_norm, s_norm, self.cfg.admm))
+            rho = admm.new_penalty(jnp.float32(self.rho), r_norm, s_norm,
+                                   self.cfg.admm)
+            with spans.span("round.rho.wait"):
+                self.rho = float(rho)
         if self.rho != rho_old:
             # broadcast of the new penalty: workers rescale their scaled
             # duals u = y/rho (Boyd §3.4.1; see core.admm.new_penalty)
@@ -447,48 +464,55 @@ class Scheduler:
         batched = self._engine_batched
         fresh: Dict[int, Tuple[jnp.ndarray, float]] = {}
         extras = np.zeros(W)
+        rec = spans.current()
+        ls_trials = None
         if batched:
             q_all, iters_all, omegas, extras = self._all_worker_passes()
-            for wid in range(W):
-                inner[wid] = iters_all[self._logical(wid)]
+            lanes = np.arange(W) // self.repl
+            inner[:] = iters_all[lanes]
+            ls = None if rec is None else rec.counters.get("ls_trials")
+            if ls is not None:
+                ls_trials = np.asarray(ls, np.int64)[lanes]
         else:
+            with spans.span("round.solve"):
+                for wid in range(W):
+                    omega, q, it, extra = self._worker_pass(wid)
+                    inner[wid] = it
+                    extras[wid] = extra
+                    fresh[wid] = (omega, q)
+
+        with spans.span("round.timing"):
+            timing_iters = inner.copy()
+            if cfg.iter_smoothing:
+                timing_iters[:] = max(int(np.median(inner)), 1)
+            arrivals = []
+            # z is broadcast DENSE (only the ω uplink is compressed)
+            rx = self.pool.comm_time(4 * self.wire_d)
+            tx = self.pool.comm_time(self.msg_bytes)
             for wid in range(W):
-                omega, q, it, extra = self._worker_pass(wid)
-                inner[wid] = it
-                extras[wid] = extra
-                fresh[wid] = (omega, q)
+                lw = self._logical(wid)
+                tc = self.pool.compute_time(
+                    self.pool.workers[wid], int(timing_iters[wid]),
+                    self.problem.n_samples(lw, self.n_logical))
+                t_comp[wid] = tc
+                t_comm[wid] = rx + tx                  # rx z + tx ω
+                arrivals.append((round_start + extras[wid] + rx + tc + tx,
+                                 wid))
 
-        timing_iters = inner.copy()
-        if cfg.iter_smoothing:
-            timing_iters[:] = max(int(np.median(inner)), 1)
-        arrivals = []
-        # z is broadcast DENSE (only the ω uplink is compressed)
-        rx = self.pool.comm_time(4 * self.wire_d)
-        tx = self.pool.comm_time(self.msg_bytes)
-        for wid in range(W):
-            lw = self._logical(wid)
-            tc = self.pool.compute_time(
-                self.pool.workers[wid], int(timing_iters[wid]),
-                self.problem.n_samples(lw, self.n_logical))
-            t_comp[wid] = tc
-            t_comm[wid] = rx + tx                      # rx z + tx ω
-            arrivals.append((round_start + extras[wid] + rx + tc + tx,
-                             wid))
-
-        # -- which messages does the master wait for? -----------------------
-        if cfg.mode == "drop_slowest":
-            n_wait = W - int(cfg.drop_frac * W)
-            waited = sorted(arrivals)[:n_wait]
-        elif cfg.mode == "replicated":
-            # first responder per FRS group (replicas are exact copies)
-            waited, seen = [], set()
-            for t, wid in sorted(arrivals):
-                g = self._logical(wid)
-                if g not in seen:
-                    seen.add(g)
-                    waited.append((t, wid))
-        else:
-            waited = sorted(arrivals)
+            # -- which messages does the master wait for? -------------------
+            if cfg.mode == "drop_slowest":
+                n_wait = W - int(cfg.drop_frac * W)
+                waited = sorted(arrivals)[:n_wait]
+            elif cfg.mode == "replicated":
+                # first responder per FRS group (replicas are exact copies)
+                waited, seen = [], set()
+                for t, wid in sorted(arrivals):
+                    g = self._logical(wid)
+                    if g not in seen:
+                        seen.add(g)
+                        waited.append((t, wid))
+            else:
+                waited = sorted(arrivals)
 
         # update the running ω table (stale-cache semantics: unwaited slots
         # keep their previous ω, so the mean stays over all workers); local
@@ -496,65 +520,74 @@ class Scheduler:
         # the master does not wait for them.  Undelivered messages must
         # not advance the codec's shared view either (their content rides
         # in a later delta instead of being smuggled in for free).
-        waited_lws = {self._logical(wid) for _, wid in waited}
-        self.codec.rollback_except(codec_snap, waited_lws)
-        if batched:
-            # vectorized table update + wholesale commit: one scatter for
-            # the waited slots instead of W per-row device ops (the
-            # unwaited slots keep their stale ω, same as the loop path)
-            idx = np.fromiter(sorted(waited_lws), np.int64)
-            jidx = jnp.asarray(idx)
-            self.omega_table = self.omega_table.at[jidx].set(omegas[jidx])
-            self.q_table[idx] = q_all[idx]
-            self.x, self.u = self._batched_xu
-        else:
-            for _, wid in waited:
-                om, q = fresh[wid]
-                lw = self._logical(wid)
-                self.omega_table = self.omega_table.at[lw].set(om)
-                self.q_table[lw] = q
-            for lw in self._round_results:
-                self._commit_xu(lw)
+        with spans.span("round.commit"):
+            waited_lws = {self._logical(wid) for _, wid in waited}
+            self.codec.rollback_except(codec_snap, waited_lws)
+            if batched:
+                # vectorized table update + wholesale commit: one scatter
+                # for the waited slots instead of W per-row device ops
+                # (the unwaited slots keep their stale ω, as in the loop
+                # path)
+                idx = np.fromiter(sorted(waited_lws), np.int64)
+                jidx = jnp.asarray(idx)
+                self.omega_table = self.omega_table.at[jidx].set(
+                    omegas[jidx])
+                self.q_table[idx] = q_all[idx]
+                self.x, self.u = self._batched_xu
+            else:
+                for _, wid in waited:
+                    om, q = fresh[wid]
+                    lw = self._logical(wid)
+                    self.omega_table = self.omega_table.at[lw].set(om)
+                    self.q_table[lw] = q
+                for lw in self._round_results:
+                    self._commit_xu(lw)
 
         # -- scheduler fan-in timing (Fig 5 cliff vs the tree fix) ----------
-        master_done = fanin_drain(waited, cfg.fanin, self.pool, cfg.tree,
-                                  self.msg_bytes, W)
+        with spans.span("round.fanin"):
+            master_done = fanin_drain(waited, cfg.fanin, self.pool,
+                                      cfg.tree, self.msg_bytes, W)
 
-        omega_bar = jnp.mean(self.omega_table, axis=0)
-        q_sum = float(self.q_table.sum())
-        r_norm, s_norm = self._master_z_update(omega_bar, q_sum,
-                                               self.n_logical)
+        with spans.span("round.master"):
+            omega_bar = jnp.mean(self.omega_table, axis=0)
+            q_sum = float(self.q_table.sum())
+            r_norm, s_norm = self._master_z_update(omega_bar, q_sum,
+                                                   self.n_logical)
 
-        bcast = self.pool.comm_time(4 * self.wire_d)
-        self.sim_time = master_done + bcast
-        round_wall = self.sim_time - round_start
-        t_idle = round_wall - t_comp
-        self.k += 1
+        with spans.span("round.bill"):
+            bcast = self.pool.comm_time(4 * self.wire_d)
+            self.sim_time = master_done + bcast
+            round_wall = self.sim_time - round_start
+            t_idle = round_wall - t_comp
+            self.k += 1
 
-        # the bill: every worker holds its memory for the whole round
-        # (idle time at the barrier is billed time — the serverless cost
-        # story), every omega uplink + z downlink crosses the boundary,
-        # and the coordinator runs throughout.  Mid-round respawn init
-        # spans (extras) are carved out of the respawned workers' billed
-        # time — init billing is _bill_spawns' job, gated on
-        # bill_cold_init — while the OTHER workers' barrier wait on those
-        # respawns stays billed.
-        self._bill_spawns()
-        self.meter.record_duration(round_wall * W - float(extras.sum()))
-        self.meter.record_master(round_wall)
-        self.meter.record_bytes(W * (self.msg_bytes + 4 * self.wire_d))
+            # the bill: every worker holds its memory for the whole round
+            # (idle time at the barrier is billed time — the serverless
+            # cost story), every omega uplink + z downlink crosses the
+            # boundary, and the coordinator runs throughout.  Mid-round
+            # respawn init spans (extras) are carved out of the respawned
+            # workers' billed time — init billing is _bill_spawns' job,
+            # gated on bill_cold_init — while the OTHER workers' barrier
+            # wait on those respawns stays billed.
+            self._bill_spawns()
+            self.meter.record_duration(round_wall * W - float(extras.sum()))
+            self.meter.record_master(round_wall)
+            self.meter.record_bytes(W * (self.msg_bytes + 4 * self.wire_d))
 
-        thresh = np.quantile([t for t, _ in arrivals], 0.9)
-        m = RoundMetrics(
-            k=self.k, sim_time=self.sim_time, r_norm=r_norm, s_norm=s_norm,
-            rho=self.rho, t_comp=t_comp, t_comm=t_comm, t_idle=t_idle,
-            inner_iters=inner, n_respawns=self.n_respawns,
-            slowest10=np.array([t >= thresh for t, _ in arrivals]),
-            round_wall_s=round_wall,
-            t_fanin_wait=master_done - max(t for t, _ in waited),
-            cost_usd=self.meter.total_usd(), n_workers=W,
-            z_nnz=self._z_nnz)
-        self.history.append(m)
+            thresh = np.quantile([t for t, _ in arrivals], 0.9)
+            m = RoundMetrics(
+                k=self.k, sim_time=self.sim_time, r_norm=r_norm,
+                s_norm=s_norm, rho=self.rho, t_comp=t_comp, t_comm=t_comm,
+                t_idle=t_idle, inner_iters=inner,
+                n_respawns=self.n_respawns,
+                slowest10=np.array([t >= thresh for t, _ in arrivals]),
+                round_wall_s=round_wall,
+                t_fanin_wait=master_done - max(t for t, _ in waited),
+                cost_usd=self.meter.total_usd(), n_workers=W,
+                z_nnz=self._z_nnz,
+                span_s=None if rec is None else rec.span_s,
+                ls_trials=ls_trials)
+            self.history.append(m)
         return m
 
     # ------------------------------------------------------------------
@@ -647,6 +680,7 @@ class Scheduler:
         self.meter.record_bytes(W * (self.msg_bytes + 4 * self.wire_d))
 
         thresh = np.quantile([t for t, _ in arrivals], 0.9)
+        rec = spans.current()
         m = RoundMetrics(
             k=self.k, sim_time=self.sim_time, r_norm=r_norm, s_norm=s_norm,
             rho=self.rho, t_comp=t_comp, t_comm=t_comm, t_idle=t_idle,
@@ -654,7 +688,8 @@ class Scheduler:
             slowest10=np.array([t >= thresh for t, _ in arrivals]),
             round_wall_s=round_wall,
             t_fanin_wait=master_done - max(t for t, _ in waited),
-            cost_usd=self.meter.total_usd(), n_workers=W, z_nnz=-1)
+            cost_usd=self.meter.total_usd(), n_workers=W, z_nnz=-1,
+            span_s=None if rec is None else rec.span_s)
         self.history.append(m)
         return m
 
@@ -780,22 +815,24 @@ class Scheduler:
                              "async_ paces itself per-arrival (run_async)")
         if cfg.autoscale.policy != "off" and self.autoscaler is None:
             self.autoscaler = Autoscaler(cfg.autoscale, quantum=self.repl)
-        m = (self.run_round_newton() if self._second_order
-             else self.run_round())
-        if on_round:
-            on_round(m)
-        if (m.r_norm <= cfg.admm.eps_primal
-                and m.s_norm <= cfg.admm.eps_dual):
-            return m, True
-        if self.autoscaler is not None:
-            self.autoscaler.observe(
-                round_wall_s=m.round_wall_s,
-                t_comp_mean=float(m.t_comp.mean()),
-                t_fanin_wait=m.t_fanin_wait)
-            new_w = self.autoscaler.decide(self.cfg.n_workers)
-            if new_w is not None:
-                self.rescale(new_w)
-        return m, False
+        # the round's record stays open until the "round" span has closed,
+        # so the metrics this returns (and history holds) carry every span
+        with spans.record(), spans.span("round"):
+            m = (self.run_round_newton() if self._second_order
+                 else self.run_round())
+            if on_round:
+                on_round(m)
+            done = (m.r_norm <= cfg.admm.eps_primal
+                    and m.s_norm <= cfg.admm.eps_dual)
+            if not done and self.autoscaler is not None:
+                self.autoscaler.observe(
+                    round_wall_s=m.round_wall_s,
+                    t_comp_mean=float(m.t_comp.mean()),
+                    t_fanin_wait=m.t_fanin_wait)
+                new_w = self.autoscaler.decide(self.cfg.n_workers)
+                if new_w is not None:
+                    self.rescale(new_w)
+        return m, bool(done)
 
     def solve(self, *, max_rounds: Optional[int] = None,
               on_round: Optional[Callable] = None) -> jnp.ndarray:
