@@ -45,23 +45,62 @@ class FistaState(NamedTuple):
 
 def _backtrack(vg: Callable, y, f_y, g_y, lip, opts: FistaOptions):
     """Find L (by eta-doubling) with F(y - g/L) <= F(y) - ||g||^2/(2L).
-    Returns (L, the number of trials made, each one pass of F)."""
+
+    Every L the search ends on is evaluated: the accepted trial, or, when
+    all ``max_backtracks`` trials fail, one more pass at the last doubled L
+    that grows nothing and is not counted.  Returns (L, y - g/L, F there,
+    the number of trials made, each one pass of F)."""
     gsq = jnp.vdot(g_y, g_y).real
 
     def cond(carry):
-        lip, j, ok = carry
-        return jnp.logical_and(~ok, j < opts.max_backtracks)
+        _, _, done, _ = carry
+        return ~done
 
     def body(carry):
-        lip, j, _ = carry
+        lip, j, _, _ = carry
         x_try = y - g_y / lip
         f_try, _ = vg(x_try)
+        trial = j < opts.max_backtracks
         ok = f_try <= f_y - 0.5 * gsq / lip + 1e-12 * jnp.abs(f_y)
-        lip_next = jnp.where(ok, lip, lip * opts.eta)
-        return (lip_next, j + 1, ok)
+        grow = jnp.logical_and(trial, ~ok)
+        lip_next = jnp.where(grow, lip * opts.eta, lip)
+        return (lip_next, j + trial.astype(j.dtype), ~grow, f_try)
 
-    lip, j, _ = jax.lax.while_loop(cond, body, (lip, jnp.int32(0), jnp.asarray(False)))
-    return lip, j
+    lip, j, _, f_new = jax.lax.while_loop(
+        cond, body, (lip, jnp.int32(0), jnp.asarray(False), f_y))
+    # the last trial's point, by the same expression: carrying it out of
+    # the loop instead costs a (W, d) carry that kept y out of the TPU's
+    # VMEM, slowing the next iteration's gather of y
+    return lip, y - g_y / lip, f_new, j
+
+
+def _init(value_and_grad: Callable, x0, opts: FistaOptions) -> FistaState:
+    f0, _ = value_and_grad(x0)
+    ft = f0.dtype
+    return FistaState(
+        x=x0, y=x0, t=jnp.asarray(1.0, ft), lip=jnp.asarray(opts.l0, ft),
+        f_x=f0, g_norm=jnp.asarray(jnp.inf, ft),
+        rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0),
+        n_ls=jnp.int32(0))
+
+
+def _step(value_and_grad: Callable, st: FistaState,
+          opts: FistaOptions) -> FistaState:
+    """One FISTA iteration; F(x_new) is the line search's last evaluation."""
+    f_y, g_y = value_and_grad(st.y)
+    lip, x_new, f_new, n_try = _backtrack(value_and_grad, st.y, f_y, g_y,
+                                          st.lip, opts)
+    # monotone safeguard (MFISTA-lite): never accept an increase over x_k
+    worse = f_new > st.f_x
+    x_new = jnp.where(worse, st.x, x_new)
+    f_new = jnp.where(worse, st.f_x, f_new)
+    t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * st.t * st.t))
+    y_new = x_new + ((st.t - 1.0) / t_new) * (x_new - st.x)
+    rel = (st.f_x - f_new) / jnp.maximum(jnp.abs(st.f_x), 1e-30)
+    return FistaState(
+        x=x_new, y=y_new, t=t_new, lip=lip, f_x=f_new,
+        g_norm=jnp.linalg.norm(g_y), rel_impr=rel, k=st.k + 1,
+        n_ls=st.n_ls + n_try)
 
 
 def fista(
@@ -70,14 +109,6 @@ def fista(
     opts: FistaOptions = FistaOptions(),
 ) -> Tuple[jnp.ndarray, FistaState]:
     """Minimise F from ``value_and_grad``; returns (x*, final state)."""
-    f0, _ = value_and_grad(x0)
-    ft = f0.dtype
-    init = FistaState(
-        x=x0, y=x0, t=jnp.asarray(1.0, ft), lip=jnp.asarray(opts.l0, ft),
-        f_x=f0, g_norm=jnp.asarray(jnp.inf, ft),
-        rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0),
-        n_ls=jnp.int32(0))
-
     def cond(st: FistaState):
         not_min = st.k < opts.min_iters
         under_max = st.k < opts.max_iters
@@ -87,51 +118,16 @@ def fista(
                                jnp.logical_or(not_min,
                                               jnp.logical_and(grad_big, impr_big)))
 
-    def body(st: FistaState):
-        f_y, g_y = value_and_grad(st.y)
-        lip, n_try = _backtrack(value_and_grad, st.y, f_y, g_y, st.lip, opts)
-        x_new = st.y - g_y / lip
-        f_new, _ = value_and_grad(x_new)
-        # monotone safeguard (MFISTA-lite): never accept an increase over x_k
-        worse = f_new > st.f_x
-        x_new = jnp.where(worse, st.x, x_new)
-        f_new = jnp.where(worse, st.f_x, f_new)
-        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * st.t * st.t))
-        y_new = x_new + ((st.t - 1.0) / t_new) * (x_new - st.x)
-        rel = (st.f_x - f_new) / jnp.maximum(jnp.abs(st.f_x), 1e-30)
-        return FistaState(
-            x=x_new, y=y_new, t=t_new, lip=lip, f_x=f_new,
-            g_norm=jnp.linalg.norm(g_y), rel_impr=rel, k=st.k + 1,
-            n_ls=st.n_ls + n_try)
-
-    final = jax.lax.while_loop(cond, body, init)
+    final = jax.lax.while_loop(
+        cond, lambda st: _step(value_and_grad, st, opts),
+        _init(value_and_grad, x0, opts))
     return final.x, final
 
 
 def fista_fixed(value_and_grad, x0, n_iters: int, opts: FistaOptions = FistaOptions()):
     """Fixed-iteration-count FISTA (scan) — used when a static trip count is
     needed (e.g. inside vmapped workers during the dry-run)."""
-    def body(st: FistaState, _):
-        f_y, g_y = value_and_grad(st.y)
-        lip, n_try = _backtrack(value_and_grad, st.y, f_y, g_y, st.lip, opts)
-        x_new = st.y - g_y / lip
-        f_new, _ = value_and_grad(x_new)
-        worse = f_new > st.f_x
-        x_new = jnp.where(worse, st.x, x_new)
-        f_new = jnp.where(worse, st.f_x, f_new)
-        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * st.t * st.t))
-        y_new = x_new + ((st.t - 1.0) / t_new) * (x_new - st.x)
-        rel = (st.f_x - f_new) / jnp.maximum(jnp.abs(st.f_x), 1e-30)
-        return FistaState(x=x_new, y=y_new, t=t_new, lip=lip, f_x=f_new,
-                          g_norm=jnp.linalg.norm(g_y), rel_impr=rel,
-                          k=st.k + 1, n_ls=st.n_ls + n_try), None
-
-    f0, _ = value_and_grad(x0)
-    ft = f0.dtype
-    init = FistaState(x=x0, y=x0, t=jnp.asarray(1.0, ft),
-                      lip=jnp.asarray(opts.l0, ft), f_x=f0,
-                      g_norm=jnp.asarray(jnp.inf, ft),
-                      rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0),
-                      n_ls=jnp.int32(0))
-    final, _ = jax.lax.scan(body, init, None, length=n_iters)
+    final, _ = jax.lax.scan(
+        lambda st, _: (_step(value_and_grad, st, opts), None),
+        _init(value_and_grad, x0, opts), None, length=n_iters)
     return final.x, final

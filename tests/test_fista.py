@@ -71,3 +71,207 @@ def test_logistic_vs_scipy(rng):
                    jac=lambda xn: np.asarray(
                        aug(jnp.asarray(xn, jnp.float32))[1], np.float64))
     assert float(aug(x)[0]) <= ref.fun * (1 + 1e-3) + 1e-3
+
+
+# ---------------------------------------------------------------------------
+# The iteration before F(x_{k+1}) was taken from the line search: the
+# accepted point was evaluated a second time after ``_backtrack``.  Kept
+# verbatim as the oracle that the reuse changes no number.
+# ---------------------------------------------------------------------------
+
+from repro.core.fista import FistaState
+
+
+def _oracle_backtrack(vg, y, f_y, g_y, lip, opts: FistaOptions):
+    gsq = jnp.vdot(g_y, g_y).real
+
+    def cond(carry):
+        lip, j, ok = carry
+        return jnp.logical_and(~ok, j < opts.max_backtracks)
+
+    def body(carry):
+        lip, j, _ = carry
+        x_try = y - g_y / lip
+        f_try, _ = vg(x_try)
+        ok = f_try <= f_y - 0.5 * gsq / lip + 1e-12 * jnp.abs(f_y)
+        lip_next = jnp.where(ok, lip, lip * opts.eta)
+        return (lip_next, j + 1, ok)
+
+    lip, j, _ = jax.lax.while_loop(cond, body, (lip, jnp.int32(0), jnp.asarray(False)))
+    return lip, j
+
+
+def _oracle_fista(value_and_grad, x0, opts: FistaOptions = FistaOptions()):
+    f0, _ = value_and_grad(x0)
+    ft = f0.dtype
+    init = FistaState(
+        x=x0, y=x0, t=jnp.asarray(1.0, ft), lip=jnp.asarray(opts.l0, ft),
+        f_x=f0, g_norm=jnp.asarray(jnp.inf, ft),
+        rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0),
+        n_ls=jnp.int32(0))
+
+    def cond(st: FistaState):
+        not_min = st.k < opts.min_iters
+        under_max = st.k < opts.max_iters
+        grad_big = st.g_norm > opts.eps_grad
+        impr_big = st.rel_impr > opts.eps_fval
+        return jnp.logical_and(under_max,
+                               jnp.logical_or(not_min,
+                                              jnp.logical_and(grad_big, impr_big)))
+
+    def body(st: FistaState):
+        f_y, g_y = value_and_grad(st.y)
+        lip, n_try = _oracle_backtrack(value_and_grad, st.y, f_y, g_y, st.lip, opts)
+        x_new = st.y - g_y / lip
+        f_new, _ = value_and_grad(x_new)
+        # monotone safeguard (MFISTA-lite): never accept an increase over x_k
+        worse = f_new > st.f_x
+        x_new = jnp.where(worse, st.x, x_new)
+        f_new = jnp.where(worse, st.f_x, f_new)
+        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * st.t * st.t))
+        y_new = x_new + ((st.t - 1.0) / t_new) * (x_new - st.x)
+        rel = (st.f_x - f_new) / jnp.maximum(jnp.abs(st.f_x), 1e-30)
+        return FistaState(
+            x=x_new, y=y_new, t=t_new, lip=lip, f_x=f_new,
+            g_norm=jnp.linalg.norm(g_y), rel_impr=rel, k=st.k + 1,
+            n_ls=st.n_ls + n_try)
+
+    final = jax.lax.while_loop(cond, body, init)
+    return final.x, final
+
+
+def _oracle_fista_fixed(value_and_grad, x0, n_iters: int, opts: FistaOptions = FistaOptions()):
+    def body(st: FistaState, _):
+        f_y, g_y = value_and_grad(st.y)
+        lip, n_try = _oracle_backtrack(value_and_grad, st.y, f_y, g_y, st.lip, opts)
+        x_new = st.y - g_y / lip
+        f_new, _ = value_and_grad(x_new)
+        worse = f_new > st.f_x
+        x_new = jnp.where(worse, st.x, x_new)
+        f_new = jnp.where(worse, st.f_x, f_new)
+        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * st.t * st.t))
+        y_new = x_new + ((st.t - 1.0) / t_new) * (x_new - st.x)
+        rel = (st.f_x - f_new) / jnp.maximum(jnp.abs(st.f_x), 1e-30)
+        return FistaState(x=x_new, y=y_new, t=t_new, lip=lip, f_x=f_new,
+                          g_norm=jnp.linalg.norm(g_y), rel_impr=rel,
+                          k=st.k + 1, n_ls=st.n_ls + n_try), None
+
+    f0, _ = value_and_grad(x0)
+    ft = f0.dtype
+    init = FistaState(x=x0, y=x0, t=jnp.asarray(1.0, ft),
+                      lip=jnp.asarray(opts.l0, ft), f_x=f0,
+                      g_norm=jnp.asarray(jnp.inf, ft),
+                      rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0),
+                      n_ls=jnp.int32(0))
+    final, _ = jax.lax.scan(body, init, None, length=n_iters)
+    return final.x, final
+
+
+def _quad(seed, scale=1.0):
+    r = np.random.RandomState(seed)
+    A = jnp.asarray(r.randn(40, 12) * scale, jnp.float32)
+    b = jnp.asarray(r.randn(40), jnp.float32)
+    return quad_vg(A, b), jnp.zeros(12, jnp.float32)
+
+
+def _adaptive(opts, scale=1.0):
+    def run(new):
+        vg, x0 = _quad(1, scale)
+        x, st = jax.jit(lambda x0: (fista if new else _oracle_fista)(
+            vg, x0, opts))(x0)
+        return x, st.k, st.n_ls, st.lip, st.f_x
+    return run
+
+
+def _fixed(new):
+    vg, x0 = _quad(2, 3.0)
+    opts = FistaOptions(l0=1e-3)
+    x, st = jax.jit(lambda x0: (fista_fixed if new else _oracle_fista_fixed)(
+        vg, x0, 12, opts))(x0)
+    return x, st.k, st.n_ls, st.lip, st.f_x
+
+
+def _batched_logreg(new, monkeypatch):
+    """W=3 lanes of uneven shards (padded, masked) through the batched
+    engine's worker body, ``solve_augmented`` under ``vmap``."""
+    from repro.core import fista as fista_mod
+    from repro.problems import base
+    impl = fista if new else _oracle_fista
+
+    def run_fista(vg, x0, opts):
+        # hand lip and F(x) out through solve_augmented's x slot
+        x, st = impl(vg, x0, opts)
+        return (x, st.lip, st.f_x), st
+
+    monkeypatch.setattr(fista_mod, "fista", run_fista)
+    p = base.make("logreg", n_samples=1000, n_features=64, density=0.1,
+                  fista=dict(min_iters=1, eps_grad=1e-3))
+    (batch, mask), W = p.batch_shards(3), 3
+    r = np.random.RandomState(3)
+    xs = jnp.asarray(r.randn(W, 64) * 0.1, jnp.float32)
+    us = jnp.asarray(r.randn(W, 64) * 0.05, jnp.float32)
+    z = jnp.asarray(r.randn(64) * 0.1, jnp.float32)
+
+    @jax.jit
+    def run_all(batch, mask, xs, us):
+        def one(shard, m, x0, u):
+            vg = p._masked_loss_value_and_grad(shard, m)
+            return base.solve_augmented(vg, x0, z - u, jnp.float32(1.0),
+                                        None, p.fista)
+        return jax.vmap(one)(batch, mask, xs, us)
+
+    (x, lip, f_x), k, n_ls = run_all(batch, mask, xs, us)
+    return x, k, n_ls, lip, f_x
+
+
+_EQUIV_CASES = {
+    "quadratic": _adaptive(FistaOptions(eps_grad=1e-3, max_iters=400)),
+    "heavy_backtracking": _adaptive(FistaOptions(l0=1e-3, max_iters=200),
+                                    scale=3.0),
+    "line_search_runs_out": _adaptive(FistaOptions(
+        max_backtracks=2, l0=1e-6, min_iters=15, max_iters=60), scale=3.0),
+    "no_backtracks": _adaptive(FistaOptions(max_backtracks=0, l0=400.0,
+                                            max_iters=60), scale=3.0),
+    "min_iters": _adaptive(FistaOptions(min_iters=5, eps_grad=10.0)),
+    "fista_fixed": _fixed,
+}
+
+
+@pytest.mark.parametrize("case", list(_EQUIV_CASES) + ["batched_logreg"])
+def test_reused_trial_value_is_bitwise_the_recomputed_one(case, monkeypatch):
+    """Taking F(x_{k+1}) from the accepted line-search trial changes no
+    number: x, k, the trial count, L and F(x) equal the oracle's bit for
+    bit, on every lane."""
+    if case == "batched_logreg":
+        new = _batched_logreg(True, monkeypatch)
+        old = _batched_logreg(False, monkeypatch)
+    else:
+        new, old = _EQUIV_CASES[case](True), _EQUIV_CASES[case](False)
+    for name, a, b in zip(("x", "k", "n_ls", "lip", "f_x"), new, old):
+        a, b = np.atleast_1d(a), np.atleast_1d(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert np.array_equal(a.view(np.uint8), b.view(np.uint8)), (
+            name, a, b)
+
+
+@pytest.mark.parametrize("solver", ["fista", "fista_fixed"])
+def test_value_and_grad_traced_three_times(solver):
+    """An iteration evaluates F at y and at its line-search trials only:
+    value_and_grad is traced for x0, y and the trial (the iteration
+    before this reuse traced a fourth, F(x_new) again)."""
+    vg, x0 = _quad(4)
+    run = {"fista": (fista, _oracle_fista),
+           "fista_fixed": (lambda v, x, o: fista_fixed(v, x, 3, o),
+                           lambda v, x, o: _oracle_fista_fixed(v, x, 3, o)),
+           }[solver]
+    counts = []
+    for impl in run:
+        n = [0]
+
+        def counted(x):
+            n[0] += 1
+            return vg(x)
+
+        jax.make_jaxpr(lambda x0: impl(counted, x0, FistaOptions()))(x0)
+        counts.append(n[0])
+    assert counts == [3, 4]
