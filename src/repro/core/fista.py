@@ -10,6 +10,9 @@ Termination follows Section III of the paper:
   * run at least ``min_iters`` (K_w) iterations,
   * stop when ||grad|| <= eps_g  OR  (F_{k-1} - F_k)/F_{k-1} <= eps_f,
   * hard cap at ``max_iters``.
+``FistaState.k_tol`` is the first iteration at which that tolerance held
+(``k`` if it never did), so ``k - k_tol`` counts the iterations that only
+the K_w floor asked for.
 """
 from __future__ import annotations
 
@@ -41,6 +44,7 @@ class FistaState(NamedTuple):
     rel_impr: jnp.ndarray         # last relative improvement
     k: jnp.ndarray                # iteration counter
     n_ls: jnp.ndarray             # line-search trials over all iterations
+    k_tol: jnp.ndarray            # first k at which the tolerance held, else k
 
 
 def _backtrack(vg: Callable, y, f_y, g_y, lip, opts: FistaOptions):
@@ -81,7 +85,14 @@ def _init(value_and_grad: Callable, x0, opts: FistaOptions) -> FistaState:
         x=x0, y=x0, t=jnp.asarray(1.0, ft), lip=jnp.asarray(opts.l0, ft),
         f_x=f0, g_norm=jnp.asarray(jnp.inf, ft),
         rel_impr=jnp.asarray(jnp.inf, ft), k=jnp.int32(0),
-        n_ls=jnp.int32(0))
+        n_ls=jnp.int32(0), k_tol=jnp.int32(0))
+
+
+def _tol_met(st: FistaState, opts: FistaOptions):
+    """The paper's tolerance test at ``st``: ||grad|| <= eps_g or a
+    relative decrease <= eps_f (false before the first iteration)."""
+    return ~jnp.logical_and(st.g_norm > opts.eps_grad,
+                            st.rel_impr > opts.eps_fval)
 
 
 def _step(value_and_grad: Callable, st: FistaState,
@@ -97,10 +108,12 @@ def _step(value_and_grad: Callable, st: FistaState,
     t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * st.t * st.t))
     y_new = x_new + ((st.t - 1.0) / t_new) * (x_new - st.x)
     rel = (st.f_x - f_new) / jnp.maximum(jnp.abs(st.f_x), 1e-30)
+    # k_tol stops counting at the first iterate that met the tolerance
+    held = jnp.logical_or(st.k_tol < st.k, _tol_met(st, opts))
     return FistaState(
         x=x_new, y=y_new, t=t_new, lip=lip, f_x=f_new,
         g_norm=jnp.linalg.norm(g_y), rel_impr=rel, k=st.k + 1,
-        n_ls=st.n_ls + n_try)
+        n_ls=st.n_ls + n_try, k_tol=jnp.where(held, st.k_tol, st.k + 1))
 
 
 def fista(
@@ -112,11 +125,8 @@ def fista(
     def cond(st: FistaState):
         not_min = st.k < opts.min_iters
         under_max = st.k < opts.max_iters
-        grad_big = st.g_norm > opts.eps_grad
-        impr_big = st.rel_impr > opts.eps_fval
         return jnp.logical_and(under_max,
-                               jnp.logical_or(not_min,
-                                              jnp.logical_and(grad_big, impr_big)))
+                               jnp.logical_or(not_min, ~_tol_met(st, opts)))
 
     final = jax.lax.while_loop(
         cond, lambda st: _step(value_and_grad, st, opts),

@@ -136,7 +136,8 @@ def solve_augmented(vg: Callable, x0, center, rho, fixed: Optional[int],
     """The Algorithm-2 worker body shared by both execution engines:
     minimize  f(x) + rho/2 ||x - center||^2  from x0 via FISTA (adaptive,
     or ``fista_fixed`` when ``fixed`` is set).  Jit-traceable; returns
-    (x_new, inner-iteration count, line-search trials over them)."""
+    (x_new, inner-iteration count, line-search trials over them, the
+    iteration at which the tolerance first held)."""
     def aug(x):
         f, g = vg(x)
         dx = x - center
@@ -146,7 +147,7 @@ def solve_augmented(vg: Callable, x0, center, rho, fixed: Optional[int],
         x_new, info = fista_mod.fista_fixed(aug, x0, fixed, fista_opts)
     else:
         x_new, info = fista_mod.fista(aug, x0, fista_opts)
-    return x_new, info.k, info.n_ls
+    return x_new, info.k, info.n_ls, info.k_tol
 
 
 def densify_sparse_rows(idx, vals, d: int) -> np.ndarray:
@@ -336,7 +337,9 @@ class BatchedShardProblem:
         ``kernel="pallas"`` routes each lane's loss+grad through the
         fused kernel wrappers (vmap lifts them onto one Pallas grid).
         The lanes' line-search trials go to the round's ``ls_trials``
-        counter (``runtime.spans``), read with the counts in one sync."""
+        counter and the iterations at which their tolerance first held to
+        ``tol_iters`` (``runtime.spans``), read with the counts in one
+        sync."""
         from repro.runtime import spans
         n_workers = int(xs.shape[0])
         batch, mask = (self.kernel_batch_shards(n_workers)
@@ -344,11 +347,12 @@ class BatchedShardProblem:
                        else self.batch_shards(n_workers))
         shape_key = tuple(l.shape for l in jax.tree_util.tree_leaves(batch))
         run_all = self._batched_solver(shape_key, kernel)
-        xs_new, ks, n_ls = run_all(batch, mask, xs, z, us,
-                                   jnp.asarray(rho, self.dtype))
+        xs_new, ks, n_ls, k_tol = run_all(batch, mask, xs, z, us,
+                                          jnp.asarray(rho, self.dtype))
         with spans.span("round.solve.wait"):
-            ks, n_ls = jax.device_get((ks, n_ls))
+            ks, n_ls, k_tol = jax.device_get((ks, n_ls, k_tol))
         spans.count("ls_trials", n_ls)
+        spans.count("tol_iters", k_tol)
         return xs_new, np.asarray(ks)
 
 
@@ -440,7 +444,7 @@ class FistaShardProblem(BatchedShardProblem):
         shard = self._shard(wid, n_workers)
         shapes = tuple(a.shape for a in jax.tree_util.tree_leaves(shard))
         run = self._solver(shapes)
-        x_new, k, _ = run(shard, x0, z, u, jnp.asarray(rho, self.dtype))
+        x_new, k, _, _ = run(shard, x0, z, u, jnp.asarray(rho, self.dtype))
         return x_new, int(k)
 
     # -- conformance / reporting --------------------------------------------

@@ -166,6 +166,9 @@ class RoundMetrics(NamedTuple):
     # (W,) line-search trials per worker over its FISTA iterations; None
     # where no solve recorded them (the loop engine, direct solvers)
     ls_trials: Optional[np.ndarray] = None
+    # (W,) the iteration at which each worker's FISTA tolerance first held
+    # (its count where it never did); None where ls_trials is
+    tol_iters: Optional[np.ndarray] = None
 
 
 class Scheduler:
@@ -465,14 +468,16 @@ class Scheduler:
         fresh: Dict[int, Tuple[jnp.ndarray, float]] = {}
         extras = np.zeros(W)
         rec = spans.current()
-        ls_trials = None
+        ls_trials = tol_iters = None
         if batched:
             q_all, iters_all, omegas, extras = self._all_worker_passes()
             lanes = np.arange(W) // self.repl
             inner[:] = iters_all[lanes]
-            ls = None if rec is None else rec.counters.get("ls_trials")
-            if ls is not None:
-                ls_trials = np.asarray(ls, np.int64)[lanes]
+            counters = {} if rec is None else rec.counters
+            ls_trials, tol_iters = (
+                None if counters.get(name) is None
+                else np.asarray(counters[name], np.int64)[lanes]
+                for name in ("ls_trials", "tol_iters"))
         else:
             with spans.span("round.solve"):
                 for wid in range(W):
@@ -586,7 +591,7 @@ class Scheduler:
                 cost_usd=self.meter.total_usd(), n_workers=W,
                 z_nnz=self._z_nnz,
                 span_s=None if rec is None else rec.span_s,
-                ls_trials=ls_trials)
+                ls_trials=ls_trials, tol_iters=tol_iters)
             self.history.append(m)
         return m
 

@@ -1,4 +1,6 @@
 """FISTA local solver: oracle checks against closed forms and scipy."""
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -79,7 +81,20 @@ def test_logistic_vs_scipy(rng):
 # verbatim as the oracle that the reuse changes no number.
 # ---------------------------------------------------------------------------
 
-from repro.core.fista import FistaState
+from typing import NamedTuple
+
+
+class FistaState(NamedTuple):
+    """The iteration's state before ``k_tol`` was counted."""
+    x: jnp.ndarray
+    y: jnp.ndarray
+    t: jnp.ndarray
+    lip: jnp.ndarray
+    f_x: jnp.ndarray
+    g_norm: jnp.ndarray
+    rel_impr: jnp.ndarray
+    k: jnp.ndarray
+    n_ls: jnp.ndarray
 
 
 def _oracle_backtrack(vg, y, f_y, g_y, lip, opts: FistaOptions):
@@ -199,8 +214,11 @@ def _batched_logreg(new, monkeypatch):
     impl = fista if new else _oracle_fista
 
     def run_fista(vg, x0, opts):
-        # hand lip and F(x) out through solve_augmented's x slot
+        # hand lip and F(x) out through solve_augmented's x slot; the
+        # oracle counts no k_tol
         x, st = impl(vg, x0, opts)
+        if not new:
+            st = types.SimpleNamespace(**st._asdict(), k_tol=st.k)
         return (x, st.lip, st.f_x), st
 
     monkeypatch.setattr(fista_mod, "fista", run_fista)
@@ -220,7 +238,7 @@ def _batched_logreg(new, monkeypatch):
                                         None, p.fista)
         return jax.vmap(one)(batch, mask, xs, us)
 
-    (x, lip, f_x), k, n_ls = run_all(batch, mask, xs, us)
+    (x, lip, f_x), k, n_ls, _ = run_all(batch, mask, xs, us)
     return x, k, n_ls, lip, f_x
 
 
@@ -275,3 +293,90 @@ def test_value_and_grad_traced_three_times(solver):
         jax.make_jaxpr(lambda x0: impl(counted, x0, FistaOptions()))(x0)
         counts.append(n[0])
     assert counts == [3, 4]
+
+
+# ---------------------------------------------------------------------------
+# k_tol: the iteration at which the paper's tolerance first held.  It only
+# counts; the iterates are those of the solve without it.
+# ---------------------------------------------------------------------------
+
+def _solve(opts, seed=1, scale=1.0):
+    vg, x0 = _quad(seed, scale)
+    return jax.jit(lambda x0: fista(vg, x0, opts))(x0)
+
+
+@pytest.mark.parametrize("opts, scale", [
+    (FistaOptions(eps_grad=1e-3, max_iters=400), 1.0),
+    (FistaOptions(l0=1e-3, max_iters=200), 3.0),
+    (FistaOptions(max_backtracks=2, l0=1e-6, max_iters=60), 3.0),
+    (FistaOptions(eps_grad=1e-9, eps_fval=0.0, max_iters=25), 1.0),
+], ids=["quadratic", "heavy_backtracking", "line_search_runs_out",
+        "max_iters_cap"])
+def test_k_tol_is_k_at_one_iteration_floor(opts, scale):
+    """With K_w = 1 a solve stops at its tolerance (or at max_iters, where
+    the tolerance never held), so k_tol and k agree."""
+    _, st = _solve(opts, scale=scale)
+    assert int(st.k_tol) == int(st.k) >= 1
+
+
+@pytest.mark.parametrize("eps_grad", [1e-1, 1e-2])
+def test_k_tol_under_a_floor_of_50_is_the_unfloored_k(eps_grad):
+    """Under min_iters=50 the solve makes the min_iters=1 solve's iterates
+    bit for bit until that one stops, and k_tol marks where it stopped."""
+    base = dict(eps_grad=eps_grad, max_iters=400)
+    x1, st1 = _solve(FistaOptions(min_iters=1, **base))
+    k1 = int(st1.k)
+    _, st50 = _solve(FistaOptions(min_iters=50, **base))
+    assert k1 < 50 and int(st50.k) == 50
+    assert int(st50.k_tol) == k1
+    for m in sorted({1, k1 // 2, k1}):
+        _, a = _solve(FistaOptions(min_iters=50, **dict(base, max_iters=m)))
+        _, b = _solve(FistaOptions(min_iters=1, **dict(base, max_iters=m)))
+        for name in ("x", "y", "t", "lip", "f_x", "n_ls", "k"):
+            va = np.atleast_1d(np.asarray(getattr(a, name)))
+            vb = np.atleast_1d(np.asarray(getattr(b, name)))
+            assert np.array_equal(va.view(np.uint8), vb.view(np.uint8)), name
+    assert np.array_equal(np.asarray(x1), np.asarray(_solve(FistaOptions(
+        min_iters=50, **dict(base, max_iters=k1)))[0]))
+
+
+def test_k_tol_in_a_fixed_scan():
+    """fista_fixed has no stopping rule but counts k_tol all the same."""
+    vg, x0 = _quad(1)
+    opts = FistaOptions(eps_grad=1e-1)
+    _, st1 = fista(vg, x0, opts)
+    _, stf = fista_fixed(vg, x0, int(st1.k) + 7, opts)
+    assert int(stf.k_tol) == int(st1.k) and int(stf.k) == int(st1.k) + 7
+
+
+@pytest.mark.parametrize("min_iters", [1, 50])
+def test_solve_all_reports_tol_iters_in_its_one_read(min_iters, monkeypatch):
+    """The batched engine's ``solve_all`` reads k, the trials and k_tol in
+    one ``device_get`` and records k_tol as the round's ``tol_iters``."""
+    from repro.problems import base
+    from repro.runtime import spans
+    p = base.make("logreg", n_samples=1000, n_features=64, density=0.1,
+                  fista=dict(min_iters=min_iters, eps_grad=1e-2))
+    W = 3
+    r = np.random.RandomState(5)
+    xs = jnp.asarray(r.randn(W, 64) * 0.1, jnp.float32)
+    us = jnp.asarray(r.randn(W, 64) * 0.05, jnp.float32)
+    z = jnp.asarray(r.randn(64) * 0.1, jnp.float32)
+    reads = []
+    real = jax.device_get
+
+    def counted(tree):
+        reads.append(len(tree))
+        return real(tree)
+
+    monkeypatch.setattr(jax, "device_get", counted)
+    with spans.record() as rec:
+        _, ks = p.solve_all(xs, us, z, 1.0)
+    assert reads == [3]
+    tol = rec.counters["tol_iters"]
+    assert tol.shape == (W,) and tol.dtype == np.int32
+    assert np.all(tol >= 1) and np.all(tol <= ks)
+    if min_iters == 1:
+        assert np.array_equal(tol, ks)
+    else:
+        assert np.all(ks == 50) and np.all(tol < 50)

@@ -83,16 +83,19 @@ def test_round_spans_in_the_trace_and_in_span_s(tmp_path):
             assert sum(kids) <= m.span_s[parent]
         assert m.ls_trials.shape == (4,)
         assert np.all(m.ls_trials >= m.inner_iters)
+        assert m.tol_iters.shape == (4,)
+        assert np.all(m.tol_iters <= m.inner_iters)
 
 
 def test_rounds_outside_step_carry_no_tables():
     _, sched = build(tiny_spec())
     m = sched.run_round()
-    assert m.span_s is None and m.ls_trials is None
+    assert m.span_s is None and m.ls_trials is None and m.tol_iters is None
     assert spans.current() is None
     _, loop = build(tiny_spec(engine="loop"))
     m, _ = loop.step()
-    assert m.ls_trials is None and "round.solve" in m.span_s
+    assert m.ls_trials is None and m.tol_iters is None
+    assert "round.solve" in m.span_s
 
 
 @pytest.mark.parametrize("fixed, min_iters, k",
