@@ -13,11 +13,17 @@ Termination follows Section III of the paper:
 ``FistaState.k_tol`` is the first iteration at which that tolerance held
 (``k`` if it never did), so ``k - k_tol`` counts the iterations that only
 the K_w floor asked for.
+
+``fista_lanes`` runs W such solves in one program (the batched engine's
+worker phase).  A lane's iterates are those of ``fista``; the lanes share
+the line search's passes of F, and a pass evaluates only lanes that are
+still searching.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, NamedTuple, Tuple
+import functools
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,35 +53,114 @@ class FistaState(NamedTuple):
     k_tol: jnp.ndarray            # first k at which the tolerance held, else k
 
 
+def _trial(vg: Callable, y, g_y, gsq, f_y, lip, j, opts: FistaOptions):
+    """Trial ``j`` of the line search, at L = ``lip``: evaluate F at
+    y - g/L and test F(y - g/L) <= F(y) - ||g||^2/(2L).  Returns (the next
+    L, the trials counted, whether the search is over, F there).  Trial
+    ``max_backtracks`` is not counted and grows nothing: it evaluates the
+    L a search that ran out ends on."""
+    x_try = y - g_y / lip
+    f_try, _ = vg(x_try)
+    trial = j < opts.max_backtracks
+    ok = f_try <= f_y - 0.5 * gsq / lip + 1e-12 * jnp.abs(f_y)
+    grow = jnp.logical_and(trial, ~ok)
+    lip_next = jnp.where(grow, lip * opts.eta, lip)
+    return (lip_next, j + trial.astype(j.dtype), ~grow, f_try)
+
+
 def _backtrack(vg: Callable, y, f_y, g_y, lip, opts: FistaOptions):
     """Find L (by eta-doubling) with F(y - g/L) <= F(y) - ||g||^2/(2L).
 
     Every L the search ends on is evaluated: the accepted trial, or, when
     all ``max_backtracks`` trials fail, one more pass at the last doubled L
-    that grows nothing and is not counted.  Returns (L, y - g/L, F there,
-    the number of trials made, each one pass of F)."""
+    that grows nothing and is not counted.  Returns (L, F(y - g/L), the
+    number of trials made, each one pass of F)."""
     gsq = jnp.vdot(g_y, g_y).real
-
-    def cond(carry):
-        _, _, done, _ = carry
-        return ~done
 
     def body(carry):
         lip, j, _, _ = carry
-        x_try = y - g_y / lip
-        f_try, _ = vg(x_try)
-        trial = j < opts.max_backtracks
-        ok = f_try <= f_y - 0.5 * gsq / lip + 1e-12 * jnp.abs(f_y)
-        grow = jnp.logical_and(trial, ~ok)
-        lip_next = jnp.where(grow, lip * opts.eta, lip)
-        return (lip_next, j + trial.astype(j.dtype), ~grow, f_try)
+        return _trial(vg, y, g_y, gsq, f_y, lip, j, opts)
 
     lip, j, _, f_new = jax.lax.while_loop(
-        cond, body, (lip, jnp.int32(0), jnp.asarray(False), f_y))
-    # the last trial's point, by the same expression: carrying it out of
-    # the loop instead costs a (W, d) carry that kept y out of the TPU's
-    # VMEM, slowing the next iteration's gather of y
-    return lip, y - g_y / lip, f_new, j
+        lambda carry: ~carry[2], body,
+        (lip, jnp.int32(0), jnp.asarray(False), f_y))
+    return lip, f_new, j
+
+
+def _lane_trials(vg: Callable, opts: FistaOptions) -> Callable:
+    """``_trial`` on a stack of lanes: (inputs, L, j) -> the lanes' next
+    (L, j, search over, F), ``inputs`` being (data, y, g, ||g||^2, F(y))
+    stacked by lane and lane ``w``'s F ``vg(data_w, .)``."""
+    def one(inp, lip, j):
+        d, y, g_y, gsq, f_y = inp
+        return _trial(functools.partial(vg, d), y, g_y, gsq, f_y, lip, j,
+                      opts)
+    return jax.vmap(one)
+
+
+def _compact_pass(trials: Callable, inputs, search, failing, width: int):
+    """A line-search pass over ``width`` lanes: the first ``width`` of the
+    ``failing`` lanes are taken out of ``inputs`` and the search, make
+    their next trial, and are written back.  A slot no failing lane fills
+    evaluates the last lane again (the index is clamped) and writes
+    nothing."""
+    n_lanes = failing.shape[0]
+    lanes = jnp.nonzero(failing, size=width, fill_value=n_lanes)[0]
+
+    def pick(a):
+        # a lane at a time: one gather of whole lanes of the Pallas path's
+        # dense tiles compiles for a v5e into slices across all W lanes,
+        # ~2.4 GB of temporaries at W=64
+        return jax.lax.map(
+            lambda i: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            lanes)
+
+    new = trials(jax.tree_util.tree_map(pick, inputs), pick(search[0]),
+                 pick(search[1]))
+    return tuple(a.at[lanes].set(b, mode="drop")
+                 for a, b in zip(search, new))
+
+
+def _pass_width(n_lanes: int) -> int:
+    """The width of a compacted line-search pass over ``n_lanes`` lanes:
+    an eighth of them, where a pass costs the least a lane on a v5e at
+    W=64 (its time follows its lanes, so 8 passes of 8 cost less than one
+    of 64), and two at least, since XLA compiles a batch of one lane as an
+    unbatched program whose sums may round otherwise."""
+    return min(n_lanes, max(2, n_lanes // 8))
+
+
+def _backtrack_lanes(vg: Callable, data, y, f_y, g_y, lip, live,
+                     opts: FistaOptions):
+    """``_backtrack`` on W lanes at once, lane ``w``'s F being
+    ``vg(data_w, .)``, ``data_w`` the w-th slice of ``data`` along its
+    leading axis.
+
+    A ``live`` lane searches until a trial passes or its search runs out.
+    Each pass takes ``_pass_width(W)`` of the searching lanes, compacted,
+    until none is left.  A lane's trials are those ``_backtrack`` makes,
+    at the same points, by the same expression.  Returns (L, F(y - g/L),
+    trials) per lane and the lane evaluations the passes made, every slot
+    of every pass counted."""
+    n_lanes = y.shape[0]
+    width = _pass_width(n_lanes)
+    gsq = jax.vmap(lambda g: jnp.vdot(g, g).real)(g_y)
+    inputs = (data, y, g_y, gsq, f_y)
+    trials = _lane_trials(vg, opts)
+
+    def failing(search):
+        return jnp.logical_and(live, ~search[2])
+
+    def compact(carry):
+        search, slots = carry
+        return (_compact_pass(trials, inputs, search, failing(search), width),
+                slots + width)
+
+    carry = (lip, jnp.zeros(lip.shape, jnp.int32),
+             jnp.zeros(lip.shape, bool), f_y), jnp.int32(0)
+    (lip, j, _, f_new), slots = jax.lax.while_loop(
+        lambda c: jnp.any(failing(c[0])), compact, carry)
+    return lip, f_new, j, slots
 
 
 def _init(value_and_grad: Callable, x0, opts: FistaOptions) -> FistaState:
@@ -95,12 +180,19 @@ def _tol_met(st: FistaState, opts: FistaOptions):
                             st.rel_impr > opts.eps_fval)
 
 
-def _step(value_and_grad: Callable, st: FistaState,
-          opts: FistaOptions) -> FistaState:
-    """One FISTA iteration; F(x_new) is the line search's last evaluation."""
-    f_y, g_y = value_and_grad(st.y)
-    lip, x_new, f_new, n_try = _backtrack(value_and_grad, st.y, f_y, g_y,
-                                          st.lip, opts)
+def _running(st: FistaState, opts: FistaOptions):
+    """The stopping rule: true while another iteration is due."""
+    not_min = st.k < opts.min_iters
+    under_max = st.k < opts.max_iters
+    return jnp.logical_and(under_max,
+                           jnp.logical_or(not_min, ~_tol_met(st, opts)))
+
+
+def _advance(st: FistaState, g_y, lip, f_new, n_try,
+             opts: FistaOptions) -> FistaState:
+    """The iteration after its line search ended on L = ``lip`` with
+    F(y - g/L) = ``f_new``."""
+    x_new = st.y - g_y / lip
     # monotone safeguard (MFISTA-lite): never accept an increase over x_k
     worse = f_new > st.f_x
     x_new = jnp.where(worse, st.x, x_new)
@@ -116,20 +208,24 @@ def _step(value_and_grad: Callable, st: FistaState,
         n_ls=st.n_ls + n_try, k_tol=jnp.where(held, st.k_tol, st.k + 1))
 
 
+def _step(value_and_grad: Callable, st: FistaState,
+          opts: FistaOptions) -> FistaState:
+    """One FISTA iteration; F(x_new) is the line search's last evaluation."""
+    f_y, g_y = value_and_grad(st.y)
+    lip, f_new, n_try = _backtrack(value_and_grad, st.y, f_y, g_y, st.lip,
+                                   opts)
+    return _advance(st, g_y, lip, f_new, n_try, opts)
+
+
 def fista(
     value_and_grad: Callable[[jnp.ndarray], Tuple[jnp.ndarray, jnp.ndarray]],
     x0: jnp.ndarray,
     opts: FistaOptions = FistaOptions(),
 ) -> Tuple[jnp.ndarray, FistaState]:
     """Minimise F from ``value_and_grad``; returns (x*, final state)."""
-    def cond(st: FistaState):
-        not_min = st.k < opts.min_iters
-        under_max = st.k < opts.max_iters
-        return jnp.logical_and(under_max,
-                               jnp.logical_or(not_min, ~_tol_met(st, opts)))
-
     final = jax.lax.while_loop(
-        cond, lambda st: _step(value_and_grad, st, opts),
+        lambda st: _running(st, opts),
+        lambda st: _step(value_and_grad, st, opts),
         _init(value_and_grad, x0, opts))
     return final.x, final
 
@@ -141,3 +237,51 @@ def fista_fixed(value_and_grad, x0, n_iters: int, opts: FistaOptions = FistaOpti
         lambda st, _: (_step(value_and_grad, st, opts), None),
         _init(value_and_grad, x0, opts), None, length=n_iters)
     return final.x, final
+
+
+def _lanes_where(live, new, old):
+    """Lane by lane, ``new`` where ``live``, else ``old``."""
+    def pick(a, b):
+        return jnp.where(live.reshape(live.shape + (1,) * (a.ndim - 1)), a, b)
+    return jax.tree_util.tree_map(pick, new, old)
+
+
+def fista_lanes(value_and_grad: Callable, data, x0: jnp.ndarray,
+                opts: FistaOptions = FistaOptions(),
+                n_iters: Optional[int] = None):
+    """W independent solves in one program: lane ``w`` minimises
+    ``value_and_grad(data_w, .)`` from ``x0[w]``, ``data_w`` the w-th slice
+    of ``data`` along its leading axis.  Lane by lane the iterates, counts
+    and trials are those of ``fista`` (of ``fista_fixed`` for ``n_iters``
+    iterations where it is given); only the line search's passes are
+    shared, each over lanes still searching (``_backtrack_lanes``).  Returns (x (W, d), the lanes' final state,
+    the lane evaluations the line search made)."""
+    n_lanes = x0.shape[0]
+    lane_vg = jax.vmap(value_and_grad)
+    running = jax.vmap(functools.partial(_running, opts=opts))
+    advance = jax.vmap(functools.partial(_advance, opts=opts))
+
+    def step(st, live, slots):
+        f_y, g_y = lane_vg(data, st.y)
+        lip, f_new, n_try, used = _backtrack_lanes(
+            value_and_grad, data, st.y, f_y, g_y, st.lip, live, opts)
+        return advance(st, g_y, lip, f_new, n_try), slots + used
+
+    init = (jax.vmap(lambda d, x: _init(
+        functools.partial(value_and_grad, d), x, opts))(data, x0),
+        jnp.int32(0))
+    if n_iters is None:
+        def body(carry):
+            st, slots = carry
+            live = running(st)
+            new, slots = step(st, live, slots)
+            return _lanes_where(live, new, st), slots
+
+        final, slots = jax.lax.while_loop(
+            lambda carry: jnp.any(running(carry[0])), body, init)
+    else:
+        every = jnp.ones((n_lanes,), bool)
+        (final, slots), _ = jax.lax.scan(
+            lambda carry, _: (step(carry[0], every, carry[1]), None),
+            init, None, length=n_iters)
+    return final.x, final, slots

@@ -131,18 +131,24 @@ def as_fista_options(fista: Union[None, dict, FistaOptions]) -> FistaOptions:
     return fista
 
 
-def solve_augmented(vg: Callable, x0, center, rho, fixed: Optional[int],
-                    fista_opts: FistaOptions):
-    """The Algorithm-2 worker body shared by both execution engines:
-    minimize  f(x) + rho/2 ||x - center||^2  from x0 via FISTA (adaptive,
-    or ``fista_fixed`` when ``fixed`` is set).  Jit-traceable; returns
-    (x_new, inner-iteration count, line-search trials over them, the
-    iteration at which the tolerance first held)."""
+def augmented(vg: Callable, center, rho) -> Callable:
+    """The Algorithm-2 worker objective  f(x) + rho/2 ||x - center||^2
+    and its gradient, from f's ``vg``."""
     def aug(x):
         f, g = vg(x)
         dx = x - center
         return f + 0.5 * rho * jnp.vdot(dx, dx), g + rho * dx
+    return aug
 
+
+def solve_augmented(vg: Callable, x0, center, rho, fixed: Optional[int],
+                    fista_opts: FistaOptions):
+    """The Algorithm-2 worker body of the loop engine: minimize
+    f(x) + rho/2 ||x - center||^2  from x0 via FISTA (adaptive, or
+    ``fista_fixed`` when ``fixed`` is set).  Jit-traceable; returns
+    (x_new, inner-iteration count, line-search trials over them, the
+    iteration at which the tolerance first held)."""
+    aug = augmented(vg, center, rho)
     if fixed is not None:
         x_new, info = fista_mod.fista_fixed(aug, x0, fixed, fista_opts)
     else:
@@ -203,7 +209,7 @@ class BatchedShardProblem:
 
         solve_all(xs, us, z, rho) -> (xs_new (W, d), inner_iters (W,))
 
-    as a single ``jax.vmap``-ed, jitted call (``SchedulerConfig(
+    as a single jitted call over all W lanes (``SchedulerConfig(
     engine="batched")`` selects it).  Shards of unequal length — W not
     dividing the sample count — are zero-padded to the longest shard and
     a per-row {0,1} mask rides along, so every lane has one static shape.
@@ -217,9 +223,10 @@ class BatchedShardProblem:
     EXACTLY zero to both value and gradient (multiplying real rows by a
     1.0 mask is float-exact, so the two engines agree to vmap-reduction
     tolerance — allclose, not bitwise).  Per-lane FISTA keeps its own
-    data-dependent iteration count: ``lax.while_loop`` under ``vmap``
-    masks finished lanes, so a lane's trajectory and its reported
-    ``inner_iters`` match the unbatched solve.
+    data-dependent iteration count: ``fista.fista_lanes`` masks finished
+    lanes, so a lane's trajectory and its reported ``inner_iters`` match
+    the unbatched solve, and runs a line-search retry over the lanes still
+    searching alone.
 
     Batches are cached per fleet size W, which is what makes elastic
     ``rescale()`` compose for free: a new W is a cache miss that
@@ -318,13 +325,13 @@ class BatchedShardProblem:
 
             @jax.jit
             def run_all(batch, mask, xs, z, us, rho):
-                def one(shard, m, x0, u):
-                    vg = hook(shard, m)
-                    return solve_augmented(vg, x0, z - u, rho, fixed,
-                                           fista_opts)
+                def lane_vg(lane, x):
+                    shard, m, center = lane
+                    return augmented(hook(shard, m), center, rho)(x)
 
-                return jax.vmap(one, in_axes=(0, 0, 0, 0))(
-                    batch, mask, xs, us)
+                x_new, st, slots = fista_mod.fista_lanes(
+                    lane_vg, (batch, mask, z - us), xs, fista_opts, fixed)
+                return x_new, st.k, st.n_ls, st.k_tol, slots
 
             self._batched_solver_cache[cache_key] = run_all
         return self._batched_solver_cache[cache_key]
@@ -337,9 +344,10 @@ class BatchedShardProblem:
         ``kernel="pallas"`` routes each lane's loss+grad through the
         fused kernel wrappers (vmap lifts them onto one Pallas grid).
         The lanes' line-search trials go to the round's ``ls_trials``
-        counter and the iterations at which their tolerance first held to
-        ``tol_iters`` (``runtime.spans``), read with the counts in one
-        sync."""
+        counter, the iterations at which their tolerance first held to
+        ``tol_iters`` and the lane evaluations the line search's passes
+        made to ``ls_slots`` (``runtime.spans``), read with the counts in
+        one sync."""
         from repro.runtime import spans
         n_workers = int(xs.shape[0])
         batch, mask = (self.kernel_batch_shards(n_workers)
@@ -347,12 +355,13 @@ class BatchedShardProblem:
                        else self.batch_shards(n_workers))
         shape_key = tuple(l.shape for l in jax.tree_util.tree_leaves(batch))
         run_all = self._batched_solver(shape_key, kernel)
-        xs_new, ks, n_ls, k_tol = run_all(batch, mask, xs, z, us,
-                                          jnp.asarray(rho, self.dtype))
+        xs_new, ks, n_ls, k_tol, slots = run_all(
+            batch, mask, xs, z, us, jnp.asarray(rho, self.dtype))
         with spans.span("round.solve.wait"):
-            ks, n_ls, k_tol = jax.device_get((ks, n_ls, k_tol))
+            ks, n_ls, k_tol, slots = jax.device_get((ks, n_ls, k_tol, slots))
         spans.count("ls_trials", n_ls)
         spans.count("tol_iters", k_tol)
+        spans.count("ls_slots", int(slots))
         return xs_new, np.asarray(ks)
 
 

@@ -81,25 +81,14 @@ class LogRegProblem(base.BatchedShardProblem):
             d = self.cfg.n_features
             fista_opts = self.fista
             fixed = self.fixed_inner
-            from repro.core import fista as fista_mod
 
             @jax.jit
             def run(idx, vals, b, x0, z, u, rho):
                 vg = self._data.sparse_logistic_value_and_grad(
                     idx, vals, b, d)
-                center = z - u
-
-                def aug(x):
-                    f, g = vg(x)
-                    dx = x - center
-                    return f + 0.5 * rho * jnp.vdot(dx, dx), g + rho * dx
-
-                if fixed is not None:
-                    x_new, info = fista_mod.fista_fixed(aug, x0, fixed,
-                                                        fista_opts)
-                else:
-                    x_new, info = fista_mod.fista(aug, x0, fista_opts)
-                return x_new, info.k
+                x_new, k, _, _ = base.solve_augmented(
+                    vg, x0, z - u, rho, fixed, fista_opts)
+                return x_new, k
 
             self._solver_cache[shard_shape] = run
         return self._solver_cache[shard_shape]

@@ -169,6 +169,10 @@ class RoundMetrics(NamedTuple):
     # (W,) the iteration at which each worker's FISTA tolerance first held
     # (its count where it never did); None where ls_trials is
     tol_iters: Optional[np.ndarray] = None
+    # lane evaluations the batched solve's line search made for the W
+    # workers, every slot of every pass (a fill slot included); None where
+    # ls_trials is
+    ls_slots: Optional[int] = None
 
 
 class Scheduler:
@@ -468,7 +472,7 @@ class Scheduler:
         fresh: Dict[int, Tuple[jnp.ndarray, float]] = {}
         extras = np.zeros(W)
         rec = spans.current()
-        ls_trials = tol_iters = None
+        ls_trials = tol_iters = ls_slots = None
         if batched:
             q_all, iters_all, omegas, extras = self._all_worker_passes()
             lanes = np.arange(W) // self.repl
@@ -478,6 +482,10 @@ class Scheduler:
                 None if counters.get(name) is None
                 else np.asarray(counters[name], np.int64)[lanes]
                 for name in ("ls_trials", "tol_iters"))
+            # the r replicas of a logical worker each make its solve's
+            # evaluations, as each carries its trials
+            if counters.get("ls_slots") is not None:
+                ls_slots = int(counters["ls_slots"]) * self.repl
         else:
             with spans.span("round.solve"):
                 for wid in range(W):
@@ -591,7 +599,8 @@ class Scheduler:
                 cost_usd=self.meter.total_usd(), n_workers=W,
                 z_nnz=self._z_nnz,
                 span_s=None if rec is None else rec.span_s,
-                ls_trials=ls_trials, tol_iters=tol_iters)
+                ls_trials=ls_trials, tol_iters=tol_iters,
+                ls_slots=ls_slots)
             self.history.append(m)
         return m
 

@@ -6,8 +6,8 @@ operations) and adds its ``time.perf_counter`` seconds to the round
 record open on this thread, if one is.  ``count(name, value)`` stores a
 counter on that record.  ``Scheduler.step`` opens the record
 (``record()``) and ``RoundMetrics`` carries its tables (``span_s``,
-``ls_trials``, ``tol_iters``).  Without a profiler session a span costs two clock reads
-and a dictionary update.
+``ls_trials``, ``tol_iters``, ``ls_slots``).  Without a profiler session a
+span costs two clock reads and a dictionary update.
 
 A span name ends in ``.wait`` exactly when the span covers a blocking
 device-to-host read.

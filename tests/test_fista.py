@@ -1,4 +1,5 @@
 """FISTA local solver: oracle checks against closed forms and scipy."""
+import functools
 import types
 
 import jax
@@ -6,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.fista import FistaOptions, fista, fista_fixed
+from repro.core.fista import FistaOptions, fista, fista_fixed, fista_lanes
 
 
 def quad_vg(A, b):
@@ -351,8 +352,9 @@ def test_k_tol_in_a_fixed_scan():
 
 @pytest.mark.parametrize("min_iters", [1, 50])
 def test_solve_all_reports_tol_iters_in_its_one_read(min_iters, monkeypatch):
-    """The batched engine's ``solve_all`` reads k, the trials and k_tol in
-    one ``device_get`` and records k_tol as the round's ``tol_iters``."""
+    """The batched engine's ``solve_all`` reads k, the trials, k_tol and
+    the line search's lane evaluations in one ``device_get`` and records
+    k_tol as the round's ``tol_iters``."""
     from repro.problems import base
     from repro.runtime import spans
     p = base.make("logreg", n_samples=1000, n_features=64, density=0.1,
@@ -372,7 +374,7 @@ def test_solve_all_reports_tol_iters_in_its_one_read(min_iters, monkeypatch):
     monkeypatch.setattr(jax, "device_get", counted)
     with spans.record() as rec:
         _, ks = p.solve_all(xs, us, z, 1.0)
-    assert reads == [3]
+    assert reads == [4]
     tol = rec.counters["tol_iters"]
     assert tol.shape == (W,) and tol.dtype == np.int32
     assert np.all(tol >= 1) and np.all(tol <= ks)
@@ -380,3 +382,128 @@ def test_solve_all_reports_tol_iters_in_its_one_read(min_iters, monkeypatch):
         assert np.array_equal(tol, ks)
     else:
         assert np.all(ks == 50) and np.all(tol < 50)
+
+
+# ---------------------------------------------------------------------------
+# fista_lanes: W solves in one program, the line search's retries over the
+# lanes still searching.  Lane by lane it is ``vmap`` of the one-lane solve.
+# ---------------------------------------------------------------------------
+
+
+def _lane_quad(d, x):
+    A, b = d
+    return quad_vg(A, b)(x)
+
+
+def _quad_lanes(W, seed=7, spread=3.0):
+    """W least-squares lanes whose curvatures differ by up to 2**spread,
+    so their line searches need different numbers of trials."""
+    r = np.random.RandomState(seed)
+    scale = 2.0 ** (spread * r.rand(W))[:, None, None]
+    A = jnp.asarray(r.randn(W, 30, 10) * scale, jnp.float32)
+    b = jnp.asarray(r.randn(W, 30), jnp.float32)
+    return _lane_quad, (A, b), jnp.zeros((W, 10), jnp.float32)
+
+
+def _vmapped(vg, data, x0, opts, n_iters=None):
+    def one(d, x):
+        f = functools.partial(vg, d)
+        if n_iters is None:
+            return fista(f, x, opts)
+        return fista_fixed(f, x, n_iters, opts)
+    return jax.jit(jax.vmap(one))(data, x0)
+
+
+def _lanes(vg, data, x0, opts, n_iters=None):
+    return jax.jit(lambda d, x: fista_lanes(vg, d, x, opts, n_iters))(
+        data, x0)
+
+
+def _logreg_lanes(W):
+    """The batched engine's lanes: masked logreg shards, the last padded
+    (W does not divide the rows), through the engine's own solver."""
+    from repro.problems import base
+    p = base.make("logreg", n_samples=61 * W + 5, n_features=48,
+                  density=0.1, fista=dict(min_iters=1, eps_grad=1e-4))
+    batch, mask = p.batch_shards(W)
+    assert float(mask[-1].sum()) < mask.shape[1]
+    r = np.random.RandomState(W)
+    xs = jnp.asarray(r.randn(W, 48) * 0.1, jnp.float32)
+    us = jnp.asarray(r.randn(W, 48) * 0.05, jnp.float32)
+    z = jnp.asarray(r.randn(48) * 0.1, jnp.float32)
+    rho = jnp.float32(0.5)
+
+    def vg(lane, x):
+        shard, m, center = lane
+        return base.augmented(p._masked_loss_value_and_grad(shard, m),
+                              center, rho)(x)
+    return vg, (batch, mask, z - us), xs, p.fista
+
+
+_LANE_CASES = {
+    "curvatures": lambda: (*_quad_lanes(16), FistaOptions(
+        eps_grad=1e-3, max_iters=300), None),
+    "more_failing_than_a_pass": lambda: (*_quad_lanes(64, spread=6.0),
+                                         FistaOptions(l0=0.5, max_iters=80),
+                                         None),
+    "every_lane_failing": lambda: (*_quad_lanes(16, spread=0.5),
+                                   FistaOptions(l0=1e-4, max_iters=40), None),
+    "line_search_runs_out": lambda: (*_quad_lanes(16), FistaOptions(
+        max_backtracks=2, l0=1e-6, min_iters=15, max_iters=60), None),
+    "no_backtracks": lambda: (*_quad_lanes(16), FistaOptions(
+        max_backtracks=0, l0=4000.0, max_iters=60), None),
+    "min_iters_50": lambda: (*_quad_lanes(16), FistaOptions(
+        min_iters=50, eps_grad=1e-2), None),
+    "fista_fixed": lambda: (*_quad_lanes(16), FistaOptions(l0=1e-3), 25),
+    "logreg_w4": lambda: (*_logreg_lanes(4), None),
+    "logreg_w64": lambda: (*_logreg_lanes(64), None),
+}
+
+
+@pytest.mark.parametrize("case", list(_LANE_CASES))
+def test_lanes_are_bitwise_the_vmapped_solve(case):
+    """Lane by lane, ``fista_lanes`` makes the iterates, iteration count,
+    trials and k_tol of ``vmap`` over the one-lane ``fista`` (or
+    ``fista_fixed``), bit for bit."""
+    vg, data, x0, opts, n_iters = _LANE_CASES[case]()
+    x, st, slots = _lanes(vg, data, x0, opts, n_iters)
+    x_ref, ref = _vmapped(vg, data, x0, opts, n_iters)
+    for name, a, b in (("x", x, x_ref), ("k", st.k, ref.k),
+                       ("n_ls", st.n_ls, ref.n_ls),
+                       ("k_tol", st.k_tol, ref.k_tol),
+                       ("lip", st.lip, ref.lip), ("f_x", st.f_x, ref.f_x)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert np.array_equal(a.view(np.uint8), b.view(np.uint8)), name
+    # a pass evaluates each lane still searching once at least
+    assert int(slots) >= int(np.sum(ref.n_ls))
+    if case != "no_backtracks":
+        assert np.any(np.asarray(ref.n_ls) > np.asarray(ref.k))
+
+
+def _planted(ms, n_lanes):
+    """One iteration of lanes F_w(x) = c_w/2 ||x - 1||^2 from x = 0 with
+    c_w = 0.75 * 2**m_w: from L = 1 the search doubles L up to 2**m_w, so
+    lane w makes m_w + 1 trials (the arithmetic is exact in f32)."""
+    c = jnp.asarray(0.75 * 2.0 ** np.asarray(ms, np.float64), jnp.float32)
+
+    def vg(c, x):
+        dx = x - 1.0
+        return 0.5 * c * jnp.vdot(dx, dx), c * dx
+
+    x, st, slots = _lanes(vg, c, jnp.zeros((n_lanes, 4), jnp.float32),
+                          FistaOptions(), 1)
+    assert np.array_equal(np.asarray(st.n_ls), np.asarray(ms) + 1)
+    return int(slots)
+
+
+def test_ls_slots_counts_every_slot_of_every_pass():
+    """W=16: every pass takes the first 2 (``16 // 8``) lanes still
+    searching, fill slots counted."""
+    # lanes 0-9 need 1 trial, 10-12 need 2, 13-14 need 4 and 15 needs 8:
+    # passes take (0, 1) ... (8, 9), (10, 11) twice, (12, 13), (12, 13),
+    # (13, 14), (13, 14), (14, 15), (14, 15), then lane 15 and a fill
+    # slot six times: 19 passes, 38 slots
+    assert _planted([0] * 10 + [1, 1, 1, 3, 3, 7], 16) == 38
+    # every lane needs 4 trials: 32 passes of two
+    assert _planted([3] * 16, 16) == 4 * 16

@@ -85,16 +85,39 @@ def test_round_spans_in_the_trace_and_in_span_s(tmp_path):
         assert np.all(m.ls_trials >= m.inner_iters)
         assert m.tol_iters.shape == (4,)
         assert np.all(m.tol_iters <= m.inner_iters)
+        # every trial is one lane evaluation of a pass
+        assert isinstance(m.ls_slots, int)
+        assert m.ls_slots >= int(m.ls_trials.sum())
+
+
+def test_replicated_counters_cover_every_replica():
+    """In replicated mode the r replicas of a logical worker each carry
+    its solve's trials and its line-search evaluations, so the counters
+    of W = 8, r = 2 are those of the 4 logical workers' synchronous round
+    taken twice."""
+    _, sync = build(tiny_spec())
+    _, repl = build(ExperimentSpec(
+        problem="logreg",
+        problem_kwargs=dict(n_samples=400, n_features=30, density=0.1),
+        scheduler=SchedulerConfig(n_workers=8, engine="batched",
+                                  mode="replicated", replication=2),
+        max_rounds=10))
+    one, _ = sync.step()
+    two, _ = repl.step()
+    assert np.array_equal(two.ls_trials, np.repeat(one.ls_trials, 2))
+    assert two.ls_slots == 2 * one.ls_slots
+    assert two.ls_slots >= int(two.ls_trials.sum())
 
 
 def test_rounds_outside_step_carry_no_tables():
     _, sched = build(tiny_spec())
     m = sched.run_round()
     assert m.span_s is None and m.ls_trials is None and m.tol_iters is None
+    assert m.ls_slots is None
     assert spans.current() is None
     _, loop = build(tiny_spec(engine="loop"))
     m, _ = loop.step()
-    assert m.ls_trials is None and m.tol_iters is None
+    assert m.ls_trials is None and m.tol_iters is None and m.ls_slots is None
     assert "round.solve" in m.span_s
 
 
