@@ -180,7 +180,7 @@ class Compared:
     """Which rounds the check judges: the first job's first
     ``first_rounds`` rounds (round 1 in set-up, the next in the window)
     and the last round of the window, each with the state it started
-    from."""
+    from and its round number k in its job."""
 
     def __init__(self, initial: reference.State, first_rounds: int):
         self.initial = initial
@@ -191,18 +191,19 @@ class Compared:
 
     def add(self, state: reference.State, restarted: bool, k: int) -> None:
         prev = self.initial if k == 1 else self._prev
-        pair = (prev, state)
+        judged = (k, prev, state)
         if not restarted and k <= self.first_rounds:
-            self.first.append(pair)
-        self.last = pair
+            self.first.append(judged)
+        self.last = judged
         self._prev = state
 
-    def pairs(self) -> List:
+    def judged(self) -> List:
+        """(k, state before, state after) of each judged round."""
         out = list(self.first)
-        if self.last is not None and all(self.last[1] is not p[1]
-                                         for p in out):
+        if self.last is not None and all(self.last[2] is not j[2]
+                                         for j in out):
             out.append(self.last)
-        return [(to_host(a), to_host(b)) for a, b in out]
+        return out
 
 
 @dataclasses.dataclass
@@ -334,7 +335,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     done = done or win.restarts > 0
     while not done and first.k < cell.compare_rounds:
         m, done = step(first)
-        compared.first.append((compared.first[-1][1], snapshot(first, m)))
+        compared.first.append((m.k, compared.first[-1][2],
+                               snapshot(first, m)))
     peak_bytes = memory_peak_bytes()
 
     dev = jax.devices()[0]
@@ -342,8 +344,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     record = RunRecord(cell=cell, window=win, stage_s=stage_s,
                        trace=summary, peak=peaks)
 
-    pairs = compared.pairs()
-    del problem, sched, first, compared, spec
+    judged = compared.judged()
+    pairs = [(to_host(a), to_host(b)) for _, a, b in judged]
+    judged_rounds = [k for k, _, _ in judged]
+    del problem, sched, first, compared, judged, spec
     gc.collect()
     t_check = time.perf_counter()
     W = cell.traffic["n_workers"]
@@ -389,6 +393,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         "round_walls": win.walls,
         "round_iters": [int(max(r.inner_iters)) for r in win.rounds],
         "check_s": check_s,
+        "judged_rounds": judged_rounds,
     }
     if summary is not None:
         device["busy_s"] = summary.busy_s

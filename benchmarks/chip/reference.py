@@ -15,8 +15,9 @@ The check judges each compared round by what it left, given the state it
 started from (``check_round``): the workers' x against the reference's
 FISTA replayed from the same inputs for the same number of iterations,
 their iteration counts against those at which the paper's stopping rule
-stops that replay, and z, u and the residuals against the reference's
-master step on those x.  The workers' solves run on the device; the master's step runs on the
+stops that replay, z, u and the primal residual against the reference's
+master step on those x, and the dual residual against the round's own z.
+The workers' solves run on the device; the master's step runs on the
 host in float64.
 """
 from __future__ import annotations
@@ -232,6 +233,13 @@ def worker_inputs(prev: State, xp=np, dtype=np.float64):
     return r, u_new, z[None, :] - u_new
 
 
+def dual_residual(rho: float, z, z_prev, W: int, xp=np) -> float:
+    """rho sqrt(W) ||z - z_prev||: the dual residual of a round that moved
+    the consensus from z_prev to z, in the arithmetic of z's type."""
+    dz = z - z_prev
+    return float(rho * xp.sqrt(xp.sum(dz * dz))) * float(np.sqrt(W))
+
+
 def master_step(config: dict, prev: State, x_new, xp=np,
                 dtype=np.float64) -> State:
     """The master's half of a round on the workers' new x: the average of
@@ -251,8 +259,7 @@ def master_step(config: dict, prev: State, x_new, xp=np,
                             (1 - thr / xp.where(mag > 0, mag, 1)) * omega, 0),
                    dtype)
     r_norm = float(xp.sqrt(xp.sum(r * r)))
-    dz = z - xp.asarray(prev.z, dtype)
-    s_norm = float(rho * xp.sqrt(xp.sum(dz * dz))) * float(np.sqrt(W))
+    s_norm = dual_residual(rho, z, xp.asarray(prev.z, dtype), W, xp)
     rho_new = rho
     if r_norm > admm["mu"] * s_norm:
         rho_new = rho * admm["tau_inc"]
@@ -321,7 +328,11 @@ def check_round(config: dict, data, prev: State, cur: State
     * ``z_gap``: ||z - z_ref|| / ||z_ref||, z_ref the master's step on
       all the workers' x (``master_step``, float64).
     * ``u_gap``: the worst worker's gap to the reference's rescaled dual.
-    * ``r_gap``, ``s_gap``: relative gaps of the round's residuals.
+    * ``r_gap``: the primal residual's relative gap to float64 arithmetic
+      on the round's starting state.
+    * ``s_gap``: the dual residual's relative gap to float64 arithmetic on
+      the program's own z's (``dual_gap``); z itself is ``z_gap``'s.
+      Together: |s - s_ref| <= s_gap s_own + rho sqrt(W) z_gap ||z_ref||.
     """
     ref = master_step(config, prev, cur.x)
     replay = solve(config, data, prev, cur.iters)
@@ -339,8 +350,17 @@ def check_round(config: dict, data, prev: State, cur: State
                       float(np.linalg.norm(z_ref))),
         "u_gap": float(np.max(lane_gaps(cur.u, ref.u))),
         "r_gap": _rel(abs(cur.r_norm - ref.r_norm), ref.r_norm),
-        "s_gap": _rel(abs(cur.s_norm - ref.s_norm), ref.s_norm),
+        "s_gap": dual_gap(prev, cur),
     }
+
+
+def dual_gap(prev: State, cur: State) -> float:
+    """|s - s_own| / s_own, where s_own = rho sqrt(W) ||z - z_prev|| is
+    the dual residual the round's own z and the z it started from give,
+    in float64, at the penalty the round ran with."""
+    s_own = dual_residual(prev.rho, np.asarray(cur.z, np.float64),
+                          np.asarray(prev.z, np.float64), np.shape(cur.x)[0])
+    return _rel(abs(cur.s_norm - s_own), s_own)
 
 
 def compare(config: dict, data, pairs: Sequence) -> Dict[str, float]:

@@ -1,12 +1,14 @@
 """The correctness check fails what it has to fail: its control (the
 reference in bfloat16 put in the program's place) and a run whose timed
-path is broken underneath, for each fault a one-chip cell can have, and
-for worker solves that stop short of the paper's stopping rule.  At a
-tiny size on the CPU; the harness's look for a chip is skipped, the rest
-of the run is the benchmark's own."""
+path is broken underneath, for each fault a one-chip cell can have, for
+worker solves that stop short of the paper's stopping rule, and for a
+misreported dual residual; it passes the float32 rounding of z late in a
+job.  At a tiny size on the CPU; the harness's look for a chip is
+skipped, the rest of the run is the benchmark's own."""
 import dataclasses
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import calibrate
@@ -127,3 +129,59 @@ def test_fault_solve_stops_early(monkeypatch, fault):
     res = run(tiny_cell())
     assert res["correct"] is False
     assert over(res) == ["iter_gap"]
+
+
+def test_fault_dual_residual_misreported(monkeypatch):
+    """The scheduler reports an s_norm a thousandth too large, its z and
+    penalty sound."""
+    real = Scheduler._master_z_update
+
+    def off(self, *args, **kwargs):
+        r_norm, s_norm = real(self, *args, **kwargs)
+        return r_norm, s_norm * (1 + 1e-3)
+
+    monkeypatch.setattr(Scheduler, "_master_z_update", off)
+    res = run(tiny_cell())
+    assert res["correct"] is False
+    assert over(res) == ["s_gap"]
+
+
+def test_f32_rounding_of_z_late_in_the_job_is_no_dual_gap():
+    """A late round: z moved by a thousandth of its norm, and the
+    program's z is the float64 master's rounded to float32, every
+    coordinate up by 0.3-0.5 of an ulp.  Against the master's own dual
+    residual the program's reads that rounding over ||dz||; against the
+    residual of its own z's it reads its float32 arithmetic alone."""
+    cell = tiny_cell()
+    W, rho = 8, 1.7
+    thr = cell.config["lam1"] / (W * rho)
+    rng = np.random.default_rng(SEED)
+    v = (rng.uniform(1.0, 1.1, 4_000) + thr).astype(np.float32)
+    t = (1 - thr / v.astype(np.float64)) * v
+    z = t.astype(np.float32)
+    ulps = (z - t) / np.spacing(z)
+    assert np.all((ulps > 0.3) & (ulps < 0.5))
+    z_prev = (t * (1 - 1e-3)).astype(np.float32)
+    assert np.linalg.norm(t - z_prev) / np.linalg.norm(t) == \
+        pytest.approx(1e-3, rel=1e-3)
+    zeros = np.zeros((W, z.size), np.float32)
+    prev = reference.State(x=np.tile(z_prev, (W, 1)), u=zeros, z=z_prev,
+                           r_norm=1.0, s_norm=1.0, rho=rho)
+    s_norm = float(rho * jnp.linalg.norm(jnp.asarray(z) - z_prev)
+                   * np.sqrt(W))
+    cur = reference.State(x=np.tile(v, (W, 1)), u=zeros, z=z, r_norm=0.0,
+                          s_norm=s_norm, rho=rho)
+    ref = reference.master_step(cell.config, prev, cur.x)
+    np.testing.assert_array_equal(ref.z, z)
+    old = abs(s_norm - ref.s_norm) / ref.s_norm
+    assert old > cell.limits["s_gap"]
+    assert reference.dual_gap(prev, cur) < 1e-6
+
+
+def test_judged_rounds_end_with_the_windows_last(run_seen):
+    res, seen = run_seen(tiny_cell(), SEED)
+    assert len(seen) == 1 + res["window_rounds"] > 3
+    assert res["judged_rounds"][:3] == [1, 2, 3]
+    assert res["judged_rounds"][-1] == seen[-1].k
+    assert len(res["judged_rounds"]) <= 4
+    assert list(res)[-1] == "checks"

@@ -34,22 +34,6 @@ def over(result):
                   if not c["value"] <= c["limit"])
 
 
-def run(cell, monkeypatch):
-    """``harness.run`` off the chip, keeping every round it drove."""
-    seen = []
-    real = harness.step
-
-    def step(sched):
-        m, done = real(sched)
-        seen.append(m)
-        return m, done
-
-    monkeypatch.setattr(harness, "step", step)
-    res = harness.run(cell, SEED, 0.2, False, t_start=0.0,
-                      require_chip=False)
-    return res, seen
-
-
 def test_cell_differs_from_the_paper_cell_by_k_w_alone():
     uni, paper = harness.load_cell(UNIFORM), harness.load_cell(PAPER)
     assert uni.config["fista"] == dict(paper.config["fista"], min_iters=50)
@@ -63,8 +47,8 @@ def test_cell_differs_from_the_paper_cell_by_k_w_alone():
     assert uni.limits == dict(paper.limits, iter_gap=0.005)
 
 
-def test_tiny_uniform_run_is_correct_at_fifty_iterations(monkeypatch):
-    res, rounds = run(tiny(UNIFORM), monkeypatch)
+def test_tiny_uniform_run_is_correct_at_fifty_iterations(run_seen):
+    res, rounds = run_seen(tiny(UNIFORM), SEED)
     assert res["correct"] is True and over(res) == []
     assert res["checks"]["iter_gap"]["value"] == 0.0
     assert len(rounds) >= 3
@@ -73,7 +57,7 @@ def test_tiny_uniform_run_is_correct_at_fifty_iterations(monkeypatch):
         assert np.all(m.tol_iters < m.inner_iters)
 
 
-def test_fault_floor_ignored_fails_iter_gap(monkeypatch):
+def test_fault_floor_ignored_fails_iter_gap(monkeypatch, run_seen):
     """A program that stops each lane at its tolerance, as at K_w=1,
     reports fewer iterations than the paper's rule makes."""
     real = BatchedShardProblem.solve_all
@@ -85,7 +69,7 @@ def test_fault_floor_ignored_fails_iter_gap(monkeypatch):
         return real(self, *args, **kwargs)
 
     monkeypatch.setattr(BatchedShardProblem, "solve_all", solve_all)
-    res, _ = run(tiny(UNIFORM), monkeypatch)
+    res, _ = run_seen(tiny(UNIFORM), SEED)
     assert res["correct"] is False
     assert over(res) == ["iter_gap"]
 
