@@ -1,18 +1,9 @@
-"""Global-variable-consensus ADMM (Eqs. 5-7 / Algorithms 1-2 of the paper).
+"""Global-variable-consensus ADMM (Eqs. 5-7 / Algorithms 1-2 of the paper):
+its options and the penalty rule.
 
-Two execution styles over the same math:
-
-* ``admm_solve`` — the fully-batched "all workers as one vmapped tensor"
-  form: worker states are stacked (W, d) arrays, the per-round worker update
-  (Algorithm 2 body) runs under ``vmap``, and the master reduce is a mean
-  over the worker axis.  This is what jit/shard_map distributes on a pod —
-  the worker axis maps to the mesh "data" axis and the mean lowers to the
-  ICI all-reduce that replaces the paper's ZMQ master tree.
-
-* the event-driven form used by ``repro.runtime.scheduler`` — identical
-  per-worker math (``worker_round``), but invoked worker-by-worker by the
-  serverless pool simulator so cold starts / stragglers / failures can be
-  injected.  Both forms share ``master_update`` exactly.
+The rounds themselves run in ``repro.runtime.scheduler``: the worker
+bodies through the workload's ``solve`` / ``solve_all``, the master's
+z-update, residuals and dual rescale in ``Scheduler._master_z_update``.
 
 Notation: the paper's Algorithm 1 accumulates omega = mean_w(x^w + u^w) and
 q = sum_w ||x^w - z||^2; the z-update is the prox of h at omega with penalty
@@ -21,12 +12,9 @@ W*rho (Boyd §7.1 consensus form).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, NamedTuple, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
-from repro.core import fista as fista_mod
 from repro.core.fista import FistaOptions
 
 
@@ -43,77 +31,6 @@ class AdmmOptions:
     fista: FistaOptions = FistaOptions()
 
 
-class WorkerState(NamedTuple):
-    x: jnp.ndarray                # local primal copy (d,)
-    u: jnp.ndarray                # local (scaled) dual (d,)
-
-
-class MasterState(NamedTuple):
-    z: jnp.ndarray                # global consensus variable (d,)
-    z_prev: jnp.ndarray
-    rho: jnp.ndarray              # penalty (scalar)
-    r_norm: jnp.ndarray           # primal residual norm
-    s_norm: jnp.ndarray           # dual residual norm
-    k: jnp.ndarray                # round counter
-
-
-def init_worker(d: int) -> WorkerState:
-    return WorkerState(x=jnp.zeros((d,), jnp.float32),
-                       u=jnp.zeros((d,), jnp.float32))
-
-
-def init_master(d: int, rho0: float) -> MasterState:
-    return MasterState(z=jnp.zeros((d,), jnp.float32),
-                       z_prev=jnp.zeros((d,), jnp.float32),
-                       rho=jnp.float32(rho0),
-                       r_norm=jnp.float32(jnp.inf),
-                       s_norm=jnp.float32(jnp.inf),
-                       k=jnp.int32(0))
-
-
-# ---------------------------------------------------------------------------
-# Worker side (Algorithm 2 body)
-# ---------------------------------------------------------------------------
-
-
-def worker_round(
-    local_vg: Callable,           # value_and_grad of the local smooth loss
-    state: WorkerState,
-    z: jnp.ndarray,
-    rho: jnp.ndarray,
-    opts: FistaOptions,
-    *,
-    fixed_iters: Optional[int] = None,
-) -> Tuple[WorkerState, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One ADMM round for one worker.
-
-    Returns (new_state, q = ||x_k - z_k||^2, omega = x_{k+1} + u_{k+1},
-    inner_iters).
-    """
-    r = state.x - z
-    u_new = state.u + r
-    q = jnp.vdot(r, r).real
-
-    center = z - u_new
-
-    def aug_vg(x):
-        f, g = local_vg(x)
-        diff = x - center
-        return f + 0.5 * rho * jnp.vdot(diff, diff).real, g + rho * diff
-
-    if fixed_iters is None:
-        x_new, info = fista_mod.fista(aug_vg, state.x, opts)
-    else:
-        x_new, info = fista_mod.fista_fixed(aug_vg, state.x, fixed_iters, opts)
-    omega = x_new + u_new
-    return WorkerState(x=x_new, u=u_new), q, omega, info.k
-
-
-# ---------------------------------------------------------------------------
-# Master side (Algorithm 1 body)
-# ---------------------------------------------------------------------------
-
-
 def new_penalty(rho, r_norm, s_norm, opts: AdmmOptions):
     """Boyd §3.4.1 residual-balancing rule (the paper's new_penalty).
 
@@ -127,208 +44,3 @@ def new_penalty(rho, r_norm, s_norm, opts: AdmmOptions):
     shrink = s_norm > opts.mu * r_norm
     return jnp.where(grow, rho * opts.tau_inc,
                      jnp.where(shrink, rho / opts.tau_dec, rho))
-
-
-def master_update(
-    master: MasterState,
-    omega_bar: jnp.ndarray,       # mean_w (x^w + u^w)
-    q_sum: jnp.ndarray,           # sum_w ||x^w - z||^2
-    n_workers: int,
-    prox_h: Callable,             # prox_h(v, t) -> argmin h + 1/(2t)||.-v||^2
-    opts: AdmmOptions,
-) -> MasterState:
-    """z-update (Eq. 6), residuals, penalty adaptation."""
-    rho = master.rho
-    # Eq. 6: argmin_z h(z) + (W*rho/2)||z - omega_bar||^2
-    z_new = prox_h(omega_bar, 1.0 / (n_workers * rho))
-    r_norm = jnp.sqrt(q_sum)
-    s_norm = rho * jnp.linalg.norm(z_new - master.z) * jnp.sqrt(
-        jnp.float32(n_workers))
-    rho_new = new_penalty(rho, r_norm, s_norm, opts)
-    return MasterState(z=z_new, z_prev=master.z, rho=rho_new,
-                       r_norm=r_norm, s_norm=s_norm, k=master.k + 1)
-
-
-def converged(master: MasterState, opts: AdmmOptions) -> jnp.ndarray:
-    resid_ok = jnp.logical_and(master.r_norm <= opts.eps_primal,
-                               master.s_norm <= opts.eps_dual)
-    return jnp.logical_or(resid_ok, master.k >= opts.max_iters)
-
-
-# ---------------------------------------------------------------------------
-# Batched synchronous solve (vmap over the worker axis)
-# ---------------------------------------------------------------------------
-
-
-class AdmmTrace(NamedTuple):
-    r_norms: jnp.ndarray
-    s_norms: jnp.ndarray
-    rhos: jnp.ndarray
-    inner_iters: jnp.ndarray
-
-
-def admm_solve(
-    batched_vg: Callable,         # vg over stacked data: x (W, d) -> (f (W,), g (W, d))
-    d: int,
-    n_workers: int,
-    opts: AdmmOptions,
-    prox_h: Callable,
-    *,
-    fixed_inner: Optional[int] = None,
-    trace_len: Optional[int] = None,
-) -> Tuple[jnp.ndarray, MasterState, AdmmTrace]:
-    """Synchronous parallel consensus ADMM, workers vmapped.
-
-    ``batched_vg(x_stack)`` must return per-worker (loss, grad) for the
-    worker-local smooth losses; it is typically built by stacking the shards
-    (W, N_w, d) and vmapping ``logistic_value_and_grad``.
-
-    Returns (z*, final master state, trace of the first ``trace_len`` rounds
-    — default ``opts.max_iters``).
-    """
-    T = trace_len or opts.max_iters
-    workers0 = WorkerState(x=jnp.zeros((n_workers, d), jnp.float32),
-                           u=jnp.zeros((n_workers, d), jnp.float32))
-    master0 = init_master(d, opts.rho0)
-    trace0 = AdmmTrace(r_norms=jnp.full((T,), jnp.nan, jnp.float32),
-                       s_norms=jnp.full((T,), jnp.nan, jnp.float32),
-                       rhos=jnp.full((T,), jnp.nan, jnp.float32),
-                       inner_iters=jnp.zeros((T,), jnp.int32))
-
-    def round_fn(carry):
-        workers, master, trace = carry
-
-        # ---- Algorithm 2 (all workers at once) --------------------------
-        r = workers.x - master.z[None, :]                 # (W, d)
-        u_new = workers.u + r
-        q = jnp.sum(r * r, axis=-1)                       # (W,)
-        center = master.z[None, :] - u_new                # (W, d)
-
-        def aug_batched_vg(x_stack):
-            f, g = batched_vg(x_stack)
-            diff = x_stack - center
-            return (f + 0.5 * master.rho * jnp.sum(diff * diff, axis=-1),
-                    g + master.rho * diff)
-
-        # Batched FISTA: run FISTA on the *stacked* objective; since the
-        # objective separates over workers, per-worker backtracking and
-        # stopping are kept per-worker by vectorising the state.
-        x_new, inner = _batched_fista(aug_batched_vg, workers.x, opts.fista,
-                                      fixed_inner)
-        omega = x_new + u_new                             # (W, d)
-
-        # ---- Algorithm 1 (master reduce + z-update) ---------------------
-        omega_bar = jnp.mean(omega, axis=0)
-        q_sum = jnp.sum(q)
-        master_new = master_update(master, omega_bar, q_sum, n_workers,
-                                   prox_h, opts)
-        idx = master.k
-        trace = AdmmTrace(
-            r_norms=trace.r_norms.at[idx].set(master_new.r_norm),
-            s_norms=trace.s_norms.at[idx].set(master_new.s_norm),
-            rhos=trace.rhos.at[idx].set(master.rho),
-            inner_iters=trace.inner_iters.at[idx].set(inner.max()))
-        # rho changed -> rescale the scaled duals (see new_penalty note)
-        u_new = u_new * (master.rho / master_new.rho)
-        return (WorkerState(x=x_new, u=u_new), master_new, trace)
-
-    def cond_fn(carry):
-        _, master, _ = carry
-        return ~converged(master, opts)
-
-    workers, master, trace = jax.lax.while_loop(
-        cond_fn, round_fn, (workers0, master0, trace0))
-    return master.z, master, trace
-
-
-def _batched_fista(batched_vg, x0_stack, opts: FistaOptions,
-                   fixed_inner: Optional[int]):
-    """FISTA over a stack of independent problems sharing one vg call.
-
-    All per-iterate scalars (f, L, t, stopping flags) are (W,)-shaped; a
-    worker that has met its stopping rule freezes (masked update) until the
-    slowest worker finishes — mirroring the paper's synchronous barrier.
-    Returns (x_stack, inner_iter_counts (W,)).
-    """
-    W = x0_stack.shape[0]
-    f0, _ = batched_vg(x0_stack)
-
-    class _S(NamedTuple):
-        x: jnp.ndarray
-        y: jnp.ndarray
-        t: jnp.ndarray
-        lip: jnp.ndarray
-        f_x: jnp.ndarray
-        g_norm: jnp.ndarray
-        rel: jnp.ndarray
-        k: jnp.ndarray
-        active: jnp.ndarray
-
-    st0 = _S(x=x0_stack, y=x0_stack, t=jnp.ones((W,), jnp.float32),
-             lip=jnp.full((W,), opts.l0, jnp.float32), f_x=f0,
-             g_norm=jnp.full((W,), jnp.inf, jnp.float32),
-             rel=jnp.full((W,), jnp.inf, jnp.float32),
-             k=jnp.zeros((W,), jnp.int32),
-             active=jnp.ones((W,), bool))
-
-    max_iters = fixed_inner if fixed_inner is not None else opts.max_iters
-
-    def cond(st):
-        return jnp.any(st.active)
-
-    def body(st):
-        f_y, g_y = batched_vg(st.y)
-        gsq = jnp.sum(g_y * g_y, axis=-1)
-
-        # vectorised backtracking
-        def bt_cond(c):
-            lip, j, ok = c
-            return jnp.logical_and(jnp.any(~ok), j < opts.max_backtracks)
-
-        def bt_body(c):
-            lip, j, ok = c
-            x_try = st.y - g_y / lip[:, None]
-            f_try, _ = batched_vg(x_try)
-            ok_new = f_try <= f_y - 0.5 * gsq / lip + 1e-12 * jnp.abs(f_y)
-            lip = jnp.where(ok_new, lip, lip * opts.eta)
-            return (lip, j + 1, ok | ok_new)
-
-        lip, _, _ = jax.lax.while_loop(
-            bt_cond, bt_body,
-            (st.lip, jnp.int32(0), jnp.zeros((W,), bool)))
-
-        x_new = st.y - g_y / lip[:, None]
-        f_new, _ = batched_vg(x_new)
-        worse = f_new > st.f_x
-        x_new = jnp.where(worse[:, None], st.x, x_new)
-        f_new = jnp.where(worse, st.f_x, f_new)
-        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * st.t * st.t))
-        y_new = x_new + ((st.t - 1.0) / t_new)[:, None] * (x_new - st.x)
-        rel = (st.f_x - f_new) / jnp.maximum(jnp.abs(st.f_x), 1e-30)
-        g_norm = jnp.sqrt(gsq)
-
-        # freeze finished workers
-        upd = st.active
-        x_out = jnp.where(upd[:, None], x_new, st.x)
-        k_new = st.k + upd.astype(jnp.int32)
-
-        if fixed_inner is not None:
-            active_new = k_new < fixed_inner
-        else:
-            not_min = k_new < opts.min_iters
-            keep = jnp.logical_and(g_norm > opts.eps_grad, rel > opts.eps_fval)
-            active_new = jnp.logical_and(k_new < max_iters,
-                                         jnp.logical_or(not_min, keep))
-            active_new = jnp.logical_and(active_new, upd)
-
-        return _S(x=x_out,
-                  y=jnp.where(upd[:, None], y_new, st.y),
-                  t=jnp.where(upd, t_new, st.t),
-                  lip=jnp.where(upd, lip, st.lip),
-                  f_x=jnp.where(upd, f_new, st.f_x),
-                  g_norm=jnp.where(upd, g_norm, st.g_norm),
-                  rel=jnp.where(upd, rel, st.rel),
-                  k=k_new, active=active_new)
-
-    st = jax.lax.while_loop(cond, body, st0)
-    return st.x, st.k

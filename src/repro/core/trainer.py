@@ -23,7 +23,7 @@ Implementation notes:
    makes each *pod* one worker and FSDP-shards the worker state inside the
    pod — the paper's "worker" maps to a resource bundle, not a chip.
  * the local solver is Adam on the augmented loss (the paper's FISTA is the
-   convex special case — see repro.core.admm for the faithful logreg form).
+   convex special case — see repro.problems.logreg for the faithful form).
    Moments persist across rounds (local-SGD practice; noted in DESIGN.md).
  * the z-update applies the prox of the regularizer h: "l1" gives
    sparsity-inducing consensus (the paper's workload), "l2sq" weight-decay

@@ -91,43 +91,3 @@ def worker_shard_sparse(cfg: LogRegConfig, w: int, n_workers: int
     idx, vals, b = jax.vmap(lambda kk: _gen_row_sparse(kk, d, k))(
         _row_keys(cfg, lo, hi))
     return idx, vals, b
-
-
-def sparse_logistic_value_and_grad(idx: jnp.ndarray, vals: jnp.ndarray,
-                                   b: jnp.ndarray, d: int):
-    """vg(x) for the sparse shard form: margins via gather, grad via
-    scatter-add.  CPU-oracle twin of the dense MXU path (DESIGN.md §7)."""
-    def vg(x):
-        ax = jnp.sum(vals * x[idx], axis=-1)              # (N,)
-        margins = -b * ax
-        f = jnp.sum(jnp.logaddexp(jnp.zeros((), x.dtype), margins))
-        coef = -b * jax.nn.sigmoid(margins)               # (N,)
-        contrib = (coef[:, None] * vals).reshape(-1)
-        grad = jnp.zeros((d,), x.dtype).at[idx.reshape(-1)].add(contrib)
-        return f, grad
-    return vg
-
-
-def logistic_value_and_grad(A: jnp.ndarray, b: jnp.ndarray):
-    """Closed-form value+grad of  f(x) = sum_n log(1 + exp(-b_n <a_n, x>)).
-
-    Returns a callable vg(x) -> (f, grad); this is the pure-jnp oracle the
-    Pallas ``logistic_vjp`` kernel validates against.
-    """
-    def vg(x):
-        margins = -b * (A @ x)                            # (N,)
-        # log1p(exp(m)) computed stably
-        f = jnp.sum(jnp.logaddexp(0.0, margins))
-        sig = jax.nn.sigmoid(margins)                     # d/dm log1p(exp(m))
-        grad = A.T @ (-b * sig)
-        return f, grad
-    return vg
-
-
-def full_objective(shards, x, lam1: float) -> jnp.ndarray:
-    """phi(x) = total logistic loss + lam1*||x||_1 over a list of shards."""
-    total = lam1 * jnp.sum(jnp.abs(x))
-    for A, b in shards:
-        f, _ = logistic_value_and_grad(A, b)(x)
-        total = total + f
-    return total

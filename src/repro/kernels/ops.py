@@ -128,14 +128,6 @@ def fused_svm_vjp(A, b, x, *, gamma: float, mask=None):
     return _margin_dispatch(A, b, x, mask, loss="hinge", gamma=float(gamma))
 
 
-def logistic_value_and_grad(A, b):
-    """Drop-in replacement for data.logreg.logistic_value_and_grad that
-    routes through the fused kernel."""
-    def vg(x):
-        return fused_logistic_vjp(A, b, x)
-    return vg
-
-
 # ---------------------------------------------------------------------------
 # fused softmax value+grad (ref-backed)
 # ---------------------------------------------------------------------------

@@ -8,6 +8,17 @@ from repro.core import prox as prox_mod
 from repro.models import attention as attn_mod
 
 
+def logistic_value_and_grad(A, b):
+    """Closed-form value+grad of  f(x) = sum_n log(1 + exp(-b_n <a_n, x>))
+    over dense rows A (N, D), b (N,): vg(x) -> (f, grad)."""
+    def vg(x):
+        margins = -b * (A @ x)                            # (N,)
+        f = jnp.sum(jnp.logaddexp(0.0, margins))          # stable log1p(exp)
+        grad = A.T @ (-b * jax.nn.sigmoid(margins))
+        return f, grad
+    return vg
+
+
 def logistic_vjp_ref(a, b, mask, x):
     """a (N,D), b (N,1), mask (N,1), x (1,D) -> (loss (1,1), grad (1,D))."""
     m = -b * (a @ x.T)                                # (N,1)

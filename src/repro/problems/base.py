@@ -12,7 +12,7 @@ is a ~100-line plugin —
     @problems.register("my_workload")
     class MyProblem(problems.FistaShardProblem):
         def _gen_shard(self, wid, n_workers): ...
-        def _loss_value_and_grad(self, shard): ...
+        def _loss_value_and_grad(self, shard, mask=None): ...
         def prox_h(self, v, t): ...
         def h_value(self, z): ...
 
@@ -31,7 +31,8 @@ Batched-engine contract (optional; ``SchedulerConfig(engine="batched")``):
   * ``solve_all(xs, us, z, rho, kernel="xla")`` — all W worker bodies in
     ONE jitted, vmapped device call; provided by the
     ``BatchedShardProblem`` mixin for any workload that implements
-    ``_masked_loss_value_and_grad``.  ``kernel="pallas"`` routes the
+    ``_loss_value_and_grad(shard, mask)``, the one loss hook both engines
+    call (the loop engine without a mask).  ``kernel="pallas"`` routes the
     masked loss through the fused Pallas wrappers (``repro.kernels.ops``)
     via the optional ``_masked_kernel_loss_value_and_grad`` /
     ``kernel_batch_shards`` hooks (``SchedulerConfig(kernel="pallas")``
@@ -42,6 +43,7 @@ for every REGISTERED workload):
   * shards partition the dataset: Σ_w n_samples(w, W) == n_samples(0, 1),
   * ``solve`` decreases the augmented objective (via ``local_value``),
   * ``prox_h`` is the true prox of ``h_value`` (variational check),
+  * the loss ignores padded rows exactly,
   * a 4-worker end-to-end ``repro.api.run`` converges.
 
 Registered factories therefore also provide ``local_value(wid, W, x)``
@@ -50,6 +52,7 @@ and ``objective(x, W)`` (full φ = Σ f_w + h, for reporting).
 """
 from __future__ import annotations
 
+import os
 from typing import Callable, Dict, Optional, Protocol, Tuple, Union
 
 import jax
@@ -57,8 +60,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import fista as fista_mod
+from repro.core import prox
 from repro.core.fista import FistaOptions
-from repro.data.logreg import shard_rows
+from repro.data.logreg import shard_rows, worker_shard_sparse
 
 
 class WorkerProblem(Protocol):
@@ -141,6 +145,13 @@ def augmented(vg: Callable, center, rho) -> Callable:
     return aug
 
 
+def masked(mask, v):
+    """``v`` where there is no row mask (the loop engine), else
+    ``mask * v``: a loss applies it to each per-row term, so padded rows
+    contribute exactly zero under the batched engine."""
+    return v if mask is None else mask * v
+
+
 def solve_augmented(vg: Callable, x0, center, rho, fixed: Optional[int],
                     fista_opts: FistaOptions):
     """The Algorithm-2 worker body of the loop engine: minimize
@@ -217,12 +228,13 @@ class BatchedShardProblem:
     Host classes provide ``_shard(wid, W)`` (a pytree whose leaves are
     all row-leading), ``fista``/``fixed_inner``/``dtype``, and implement
 
-        _masked_loss_value_and_grad(shard, mask) -> vg(x) -> (f, grad)
+        _loss_value_and_grad(shard, mask=None) -> vg(x) -> (f, grad)
 
-    the masked twin of the loop path's loss: padded rows must contribute
-    EXACTLY zero to both value and gradient (multiplying real rows by a
-    1.0 mask is float-exact, so the two engines agree to vmap-reduction
-    tolerance — allclose, not bitwise).  Per-lane FISTA keeps its own
+    the loss both engines call: under the batched engine's row mask,
+    padded rows must contribute EXACTLY zero to both value and gradient
+    (``masked`` applies it; multiplying real rows by a 1.0 mask is
+    float-exact, so the two engines agree to vmap-reduction tolerance —
+    allclose, not bitwise).  Per-lane FISTA keeps its own
     data-dependent iteration count: ``fista.fista_lanes`` masks finished
     lanes, so a lane's trajectory and its reported ``inner_iters`` match
     the unbatched solve, and runs a line-search retry over the lanes still
@@ -242,18 +254,19 @@ class BatchedShardProblem:
     h_l1_lam: Optional[float] = None
 
     # -- host hooks ---------------------------------------------------------
-    def _masked_loss_value_and_grad(self, shard, mask) -> Callable:
-        """vg(x) -> (f, grad) with padded rows contributing exactly 0."""
+    def _loss_value_and_grad(self, shard, mask=None) -> Callable:
+        """vg(x) -> (f_w(x), grad f_w(x)), jit-traceable; with a row
+        ``mask``, padded rows contribute exactly 0 to both."""
         raise NotImplementedError
 
     def _masked_kernel_loss_value_and_grad(self, shard, mask) -> Callable:
-        """Fused-kernel twin of ``_masked_loss_value_and_grad``: vg built
+        """Fused-kernel form of ``_loss_value_and_grad``: vg built
         on ``repro.kernels.ops`` so each FISTA iteration streams the
         shard through ONE fused Pallas pass (value+grad together) instead
         of XLA's separate forward/backward matvecs.  The default falls
         back to the jnp path, so ``kernel="pallas"`` is safe on any
         batched workload; built-ins override it (logreg/svm/softmax)."""
-        return self._masked_loss_value_and_grad(shard, mask)
+        return self._loss_value_and_grad(shard, mask)
 
     def kernel_batch_shards(self, n_workers: int) -> Tuple:
         """The stacked batch the KERNEL solver consumes — same contract
@@ -264,11 +277,11 @@ class BatchedShardProblem:
 
     def supports_batched(self) -> bool:
         """True when this workload implements the batched path (either
-        the masked-loss hook or a full ``solve_all`` override)."""
+        the loss hook or a full ``solve_all`` override)."""
         cls = type(self)
         return (cls.solve_all is not BatchedShardProblem.solve_all
-                or cls._masked_loss_value_and_grad
-                is not BatchedShardProblem._masked_loss_value_and_grad)
+                or cls._loss_value_and_grad
+                is not BatchedShardProblem._loss_value_and_grad)
 
     def supports_kernel(self) -> bool:
         """True when ``solve_all(..., kernel="pallas")`` is accepted.
@@ -321,7 +334,7 @@ class BatchedShardProblem:
             fixed = self.fixed_inner
             hook = (self._masked_kernel_loss_value_and_grad
                     if kernel == "pallas"
-                    else self._masked_loss_value_and_grad)
+                    else self._loss_value_and_grad)
 
             @jax.jit
             def run_all(batch, mask, xs, z, us, rho):
@@ -378,9 +391,9 @@ class FistaShardProblem(BatchedShardProblem):
 
     Subclasses implement ``_gen_shard`` (a pure function of
     (seed, wid, W) — that is what makes respawn/rescale data-motion-free),
-    ``_loss_value_and_grad`` (jit-safe closure over a shard), ``prox_h``
-    and ``h_value``.  Everything else — solve, caching, conformance
-    helpers — is inherited.
+    ``_loss_value_and_grad`` (jit-safe closure over a shard and an
+    optional row mask), ``prox_h`` and ``h_value``.  Everything else —
+    solve, caching, conformance helpers — is inherited.
     """
 
     def __init__(self, n_samples: int, n_features: int, *, seed: int = 0,
@@ -398,10 +411,6 @@ class FistaShardProblem(BatchedShardProblem):
     # -- subclass hooks -----------------------------------------------------
     def _gen_shard(self, wid: int, n_workers: int):
         """Worker ``wid``'s data, a pure function of (seed, wid, W)."""
-        raise NotImplementedError
-
-    def _loss_value_and_grad(self, shard) -> Callable:
-        """vg(x) -> (f_w(x), grad f_w(x)); must be jit-traceable."""
         raise NotImplementedError
 
     def prox_h(self, v: jnp.ndarray, t: float) -> jnp.ndarray:
@@ -443,8 +452,9 @@ class FistaShardProblem(BatchedShardProblem):
             @jax.jit
             def run(shard, x0, z, u, rho):
                 vg = self._loss_value_and_grad(shard)
-                return solve_augmented(vg, x0, z - u, rho, fixed,
-                                       fista_opts)
+                x_new, k, _, _ = solve_augmented(vg, x0, z - u, rho, fixed,
+                                                 fista_opts)
+                return x_new, k
 
             self._solver_cache[shape_key] = run
         return self._solver_cache[shape_key]
@@ -453,7 +463,7 @@ class FistaShardProblem(BatchedShardProblem):
         shard = self._shard(wid, n_workers)
         shapes = tuple(a.shape for a in jax.tree_util.tree_leaves(shard))
         run = self._solver(shapes)
-        x_new, k, _, _ = run(shard, x0, z, u, jnp.asarray(rho, self.dtype))
+        x_new, k = run(shard, x0, z, u, jnp.asarray(rho, self.dtype))
         return x_new, int(k)
 
     # -- conformance / reporting --------------------------------------------
@@ -469,3 +479,61 @@ class FistaShardProblem(BatchedShardProblem):
         for w in range(n_workers):
             total += self.local_value(w, n_workers, x)
         return total
+
+
+class SparseRowsProblem(FistaShardProblem):
+    """The Koh-Kim-Boyd sparse rows of the paper's instance
+    (``data.logreg.worker_shard_sparse``, gather-format (idx, vals, b))
+    under the master regularizer h(z) = lam1 ||z||_1: the data path and
+    h that ``logreg`` and ``svm`` share.  Subclasses write their margin
+    loss (``_loss_value_and_grad``) and its fused-kernel hook."""
+
+    def __init__(self, cfg, *, fista=None, fixed_inner: Optional[int] = None,
+                 dtype="float32"):
+        super().__init__(cfg.n_samples, cfg.n_features, seed=cfg.seed,
+                         fista=fista, fixed_inner=fixed_inner, dtype=dtype)
+        self.cfg = cfg
+        self._kernel_batch_cache: Dict[int, Tuple] = {}
+
+    def _gen_shard(self, wid: int, n_workers: int):
+        """Worker ``wid``'s rows; where ``REPRO_DATA_CACHE`` names a
+        directory they are kept there as .npz (generating the full paper
+        instance costs minutes; reruns should not pay it again)."""
+        c = self.cfg
+        cache_dir = os.environ.get("REPRO_DATA_CACHE", "")
+        path = cache_dir and os.path.join(cache_dir, (
+            f"logreg_n{c.n_samples}_d{c.n_features}_p{c.density}"
+            f"_s{c.seed}_w{wid}of{n_workers}.npz"))
+        if path and os.path.exists(path):
+            with np.load(path) as f:
+                idx, vals, b = (jnp.asarray(f[k]) for k in ("idx", "vals", "b"))
+        else:
+            idx, vals, b = worker_shard_sparse(c, wid, n_workers)
+            if path:
+                os.makedirs(cache_dir, exist_ok=True)
+                np.savez(path, idx=np.asarray(idx), vals=np.asarray(vals),
+                         b=np.asarray(b))
+        return idx, vals.astype(self.dtype), b.astype(self.dtype)
+
+    def kernel_batch_shards(self, n_workers: int):
+        """Dense twin of ``batch_shards``: the Pallas margin kernels
+        stream dense MXU row tiles, so the sparse gather-format shards are
+        scattered into the kernels' padded layout once per fleet size
+        (``margin_kernel_batch``; cached, ``rescale()`` to a new W
+        re-densifies from the stacked batch)."""
+        if n_workers not in self._kernel_batch_cache:
+            self._kernel_batch_cache[n_workers] = margin_kernel_batch(
+                *self.batch_shards(n_workers), self.n_features)
+        return self._kernel_batch_cache[n_workers]
+
+    def prox_h(self, v, t):
+        return prox.prox_l1(v, t, self.cfg.lam1)
+
+    @property
+    def h_l1_lam(self):
+        """prox_h is soft-thresholding at lam1*t — exposing lam1 lets the
+        scheduler fuse the z-update (kernel="pallas")."""
+        return self.cfg.lam1
+
+    def h_value(self, z) -> float:
+        return self.cfg.lam1 * float(jnp.sum(jnp.abs(z)))

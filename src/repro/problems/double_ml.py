@@ -201,33 +201,18 @@ class DoubleMLProblem(base.FistaShardProblem):
 
     # -- losses -------------------------------------------------------------
 
-    def _loss_value_and_grad(self, shard):
+    def _loss_value_and_grad(self, shard, mask=None):
         if self.role == "nuisance":
             A, b = shard
 
             def vg(x):
-                r = A @ x - b
+                r = base.masked(mask, A @ x - b)
                 return 0.5 * jnp.vdot(r, r), A.T @ r
             return vg
         d_t, y_t = shard
 
         def vg(th):
-            r = d_t * th[0] - y_t
-            return 0.5 * jnp.vdot(r, r), jnp.array([jnp.vdot(d_t, r)])
-        return vg
-
-    def _masked_loss_value_and_grad(self, shard, mask):
-        if self.role == "nuisance":
-            A, b = shard
-
-            def vg(x):
-                r = mask * (A @ x - b)
-                return 0.5 * jnp.vdot(r, r), A.T @ r
-            return vg
-        d_t, y_t = shard
-
-        def vg(th):
-            r = mask * (d_t * th[0] - y_t)
+            r = base.masked(mask, d_t * th[0] - y_t)
             return 0.5 * jnp.vdot(r, r), jnp.array([jnp.vdot(d_t, r)])
         return vg
 

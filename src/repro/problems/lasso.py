@@ -81,21 +81,13 @@ class LassoProblem(base.FistaShardProblem):
         b = A @ self.x_true() + self.noise * eps
         return A.astype(self.dtype), b.astype(self.dtype)
 
-    def _loss_value_and_grad(self, shard):
-        A, b = shard
-
-        def vg(x):
-            r = A @ x - b
-            return 0.5 * jnp.vdot(r, r), A.T @ r
-        return vg
-
-    def _masked_loss_value_and_grad(self, shard, mask):
+    def _loss_value_and_grad(self, shard, mask=None):
         # zero-padded rows already have r = 0; the mask keeps the
         # contract explicit (and exact for any padding convention)
         A, b = shard
 
         def vg(x):
-            r = mask * (A @ x - b)
+            r = base.masked(mask, A @ x - b)
             return 0.5 * jnp.vdot(r, r), A.T @ r
         return vg
 

@@ -305,12 +305,6 @@ class LogRegL2Problem(LogRegProblem):
     def h_value(self, z) -> float:
         return 0.5 * self.lam2 * float(jnp.vdot(z, z))
 
-    def objective(self, x, n_workers: int) -> float:
-        total = self.h_value(x)
-        for w in range(n_workers):
-            total += self.local_value(w, n_workers, x)
-        return total
-
 
 @base.register("logreg_l2")
 def make_logreg_l2(n_samples: int = 2048, n_features: int = 128,

@@ -63,7 +63,11 @@ class SoftmaxProblem(base.FistaShardProblem):
         A, y = jax.vmap(row)(self._row_keys(lo, hi))
         return A.astype(self.dtype), y.astype(jnp.int32)
 
-    def _loss_value_and_grad(self, shard):
+    def _loss_value_and_grad(self, shard, mask=None):
+        # a zero-padded row has logits 0, so it would contribute log(C)
+        # to the value and a nonzero resid row — the mask zeroes both
+        # (the A=0 row already kills A.T @ resid, masking resid keeps the
+        # contract exact by construction)
         A, y = shard
         d, C = self.d_in, self.n_classes
 
@@ -72,29 +76,10 @@ class SoftmaxProblem(base.FistaShardProblem):
             logits = A @ X                                   # (N, C)
             lse = jax.scipy.special.logsumexp(logits, axis=1)
             picked = jnp.take_along_axis(logits, y[:, None], axis=1)[:, 0]
-            f = jnp.sum(lse - picked)
-            resid = jax.nn.softmax(logits, axis=1) - jax.nn.one_hot(
-                y, C, dtype=x.dtype)                         # (N, C)
-            return f, (A.T @ resid).reshape(-1)
-        return vg
-
-    def _masked_loss_value_and_grad(self, shard, mask):
-        # batched-engine twin: a zero-padded row has logits 0, so it
-        # would contribute log(C) to the value and a nonzero resid row —
-        # the mask zeroes both (the A=0 row already kills A.T @ resid,
-        # masking resid keeps the contract exact by construction)
-        A, y = shard
-        d, C = self.d_in, self.n_classes
-
-        def vg(x):
-            X = x.reshape(d, C)
-            logits = A @ X                                   # (N, C)
-            lse = jax.scipy.special.logsumexp(logits, axis=1)
-            picked = jnp.take_along_axis(logits, y[:, None], axis=1)[:, 0]
-            f = jnp.sum(mask * (lse - picked))
-            resid = mask[:, None] * (
-                jax.nn.softmax(logits, axis=1)
-                - jax.nn.one_hot(y, C, dtype=x.dtype))       # (N, C)
+            f = jnp.sum(base.masked(mask, lse - picked))
+            rows = None if mask is None else mask[:, None]
+            resid = base.masked(rows, jax.nn.softmax(logits, axis=1)
+                                - jax.nn.one_hot(y, C, dtype=x.dtype))
             return f, (A.T @ resid).reshape(-1)
         return vg
 

@@ -41,7 +41,6 @@ from repro.runtime.provider import (
 )
 from repro.runtime.reduce import TreeConfig, fanin_drain, tree_drain
 from repro.runtime.scheduler import (
-    LogRegProblem,
     RoundMetrics,
     Scheduler,
     SchedulerConfig,
@@ -49,7 +48,7 @@ from repro.runtime.scheduler import (
 
 __all__ = [
     "LambdaPool", "PoolConfig", "SimWorker",
-    "LogRegProblem", "Scheduler", "SchedulerConfig", "RoundMetrics",
+    "Scheduler", "SchedulerConfig", "RoundMetrics",
     "TreeConfig", "fanin_drain", "tree_drain",
     "Provider", "ProviderConfig", "WarmContainer",
     "InstanceClass", "DEFAULT_CLASSES", "ClassedProvider",

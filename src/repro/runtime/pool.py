@@ -1,6 +1,6 @@
 """Serverless worker-pool simulator with a discrete event clock.
 
-The optimization MATH runs for real (repro.core.admm on real shards); TIME
+The optimization MATH runs for real (consensus ADMM on real shards); TIME
 is simulated so the paper's systems experiments (cold start, stragglers,
 15-minute lifetimes, scheduler queuing) are reproducible on one host.
 Constants are calibrated against the paper's figures:

@@ -2,7 +2,7 @@
 the paper leaves as future work.
 
 The scheduler orchestrates a ``LambdaPool`` of simulated serverless workers
-running REAL ADMM math (repro.core.admm) on real shards.  Per round it
+running REAL consensus-ADMM math on real shards.  Per round it
 reproduces the paper's measurement set (idle / compute / delay per worker,
 cold starts, responsiveness) and supports:
 
@@ -62,13 +62,9 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core import admm
-from repro.core.admm import AdmmOptions, WorkerState
+from repro.core.admm import AdmmOptions
 from repro.optim.compression import OmegaCodec, message_bytes
 from repro.problems.base import WorkerProblem
-# deprecation re-export: LogRegProblem moved to repro.problems.logreg;
-# `from repro.runtime.scheduler import LogRegProblem` keeps working, new
-# code should import from repro.problems
-from repro.problems.logreg import LogRegProblem  # noqa: F401
 from repro.runtime.autoscale import AutoscaleConfig, Autoscaler
 from repro.runtime.billing import BillingConfig, BillingMeter
 from repro.runtime.pool import LambdaPool, PoolConfig
@@ -263,8 +259,8 @@ class Scheduler:
                 and getattr(problem, "supports_batched", lambda: True)()):
             raise ValueError(
                 f"engine='batched' needs the problem to implement the "
-                f"batched contract (solve_all / _masked_loss_value_and_grad"
-                f" — see repro.problems.BatchedShardProblem); "
+                f"batched contract (solve_all / _loss_value_and_grad — see "
+                f"repro.problems.BatchedShardProblem); "
                 f"{type(problem).__name__} does not")
         if cfg.kernel not in ("xla", "pallas"):
             raise ValueError(f"kernel must be 'xla' or 'pallas', "

@@ -5,9 +5,9 @@ import pytest
 from repro.configs.logreg_paper import scaled
 from repro.core.admm import AdmmOptions
 from repro.core.fista import FistaOptions
+from repro.problems import LogRegProblem
 from repro.runtime import (AutoscaleConfig, Autoscaler, PoolConfig,
                            ProviderConfig, Scheduler, SchedulerConfig)
-from repro.runtime.scheduler import LogRegProblem
 
 CFG = scaled(2048, 128, density=0.05, lam1=0.3)
 ADMM = AdmmOptions(max_iters=40)

@@ -33,14 +33,31 @@ def test_sparse_matches_dense():
 
 
 def test_sparse_vg_matches_dense_vg(rng):
+    """The logreg workload's gather-format loss is the dense logistic
+    loss on the same rows."""
+    from repro.kernels import ref
+    from repro.problems import LogRegProblem
     A, b = logreg.worker_shard(CFG, 2, 4)
-    idx, vals, bs = logreg.worker_shard_sparse(CFG, 2, 4)
     x = jnp.asarray(rng.randn(CFG.n_features) * 0.2, jnp.float32)
-    f1, g1 = logreg.logistic_value_and_grad(A, b)(x)
-    f2, g2 = logreg.sparse_logistic_value_and_grad(
-        idx, vals, bs, CFG.n_features)(x)
+    f1, g1 = ref.logistic_value_and_grad(A, b)(x)
+    p = LogRegProblem(CFG)
+    f2, g2 = p._loss_value_and_grad(p._shard(2, 4))(x)
     np.testing.assert_allclose(f1, f2, rtol=1e-5)
     np.testing.assert_allclose(g1, g2, rtol=1e-4, atol=1e-5)
+
+
+def test_data_cache_round_trips(tmp_path, monkeypatch):
+    """Shards kept under REPRO_DATA_CACHE are read back as generated."""
+    from repro.problems import LogRegProblem
+    fresh = LogRegProblem(CFG)._shard(1, 3)
+    monkeypatch.setenv("REPRO_DATA_CACHE", str(tmp_path))
+    written = LogRegProblem(CFG)._shard(1, 3)
+    assert len(list(tmp_path.iterdir())) == 1
+    read = LogRegProblem(CFG)._shard(1, 3)
+    for a, b, c in zip(fresh, written, read):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+        assert a.dtype == c.dtype
 
 
 @given(st.integers(1, 6), st.integers(1, 6))

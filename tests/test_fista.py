@@ -56,7 +56,7 @@ def test_min_iters_honored(rng):
 
 def test_logistic_vs_scipy(rng):
     from scipy.optimize import minimize
-    from repro.data.logreg import logistic_value_and_grad
+    from repro.kernels.ref import logistic_value_and_grad
     A = jnp.asarray(rng.randn(64, 12), jnp.float32)
     b = jnp.asarray(np.sign(rng.randn(64)), jnp.float32)
     rho, center = 0.5, jnp.asarray(rng.randn(12) * 0.1, jnp.float32)
@@ -234,7 +234,7 @@ def _batched_logreg(new, monkeypatch):
     @jax.jit
     def run_all(batch, mask, xs, us):
         def one(shard, m, x0, u):
-            vg = p._masked_loss_value_and_grad(shard, m)
+            vg = p._loss_value_and_grad(shard, m)
             return base.solve_augmented(vg, x0, z - u, jnp.float32(1.0),
                                         None, p.fista)
         return jax.vmap(one)(batch, mask, xs, us)
@@ -435,7 +435,7 @@ def _logreg_lanes(W):
 
     def vg(lane, x):
         shard, m, center = lane
-        return base.augmented(p._masked_loss_value_and_grad(shard, m),
+        return base.augmented(p._loss_value_and_grad(shard, m),
                               center, rho)(x)
     return vg, (batch, mask, z - us), xs, p.fista
 

@@ -13,8 +13,8 @@ import pytest
 from repro.configs.logreg_paper import scaled
 from repro.core.admm import AdmmOptions
 from repro.core.fista import FistaOptions
+from repro.problems import LogRegProblem
 from repro.runtime import PoolConfig, Scheduler, SchedulerConfig
-from repro.runtime.scheduler import LogRegProblem
 
 
 @pytest.fixture(scope="module")

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.data import logreg as logreg_mod
 from repro.kernels import decode_attention as dec_k
 from repro.kernels import flash_attention as fa_k
 from repro.kernels import logistic_vjp as lv_k
@@ -53,14 +52,14 @@ def test_logistic_vjp_padding_mask(rng):
 
 
 def test_ops_wrapper_matches_data_oracle(rng, monkeypatch):
-    """ops.fused_logistic_vjp == data.logreg closed form on odd shapes."""
+    """ops.fused_logistic_vjp == the dense closed form on odd shapes."""
     monkeypatch.setenv("REPRO_PALLAS", "interpret")
     from repro.kernels import ops
     A = _rand(rng, (111, 70), scale=0.3)
     b = jnp.asarray(np.sign(rng.randn(111)), jnp.float32)
     x = _rand(rng, (70,), scale=0.1)
     f_k, g_k = ops.fused_logistic_vjp(A, b, x)
-    f_r, g_r = logreg_mod.logistic_value_and_grad(A, b)(x)
+    f_r, g_r = ref.logistic_value_and_grad(A, b)(x)
     np.testing.assert_allclose(f_k, f_r, rtol=2e-5)
     np.testing.assert_allclose(g_k, g_r, rtol=2e-4, atol=2e-4)
 

@@ -5,10 +5,11 @@ One parametrized pass over ``repro.problems.available()``:
   * shards partition the dataset (sizes sum to n_samples),
   * ``solve`` decreases the augmented objective,
   * ``prox_h`` is the true prox of ``h_value`` (variational check),
+  * the loss ignores padded rows exactly,
   * a 4-worker end-to-end run through ``repro.api`` converges,
-plus the registry mechanics (unknown/duplicate names, plugin decorator,
-deprecation re-exports).
+plus the registry mechanics (unknown/duplicate names, plugin decorator).
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -104,6 +105,33 @@ def test_prox_h_minimizes_h_plus_quadratic(named_problem):
         assert f_star <= F(pstar + delta) + 1e-5, name
 
 
+def test_loss_ignores_padding(named_problem):
+    """The one loss hook serves both engines: an all-ones row mask is the
+    unmasked loss, and rows padded as ``batch_shards`` pads them (zeros,
+    mask 0) change neither value nor gradient."""
+    name, p = named_problem
+    assert isinstance(p, problems.FistaShardProblem), name
+    shard = p._shard(0, 2)
+    n = int(jax.tree_util.tree_leaves(shard)[0].shape[0])
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=p.n_features) * 0.2, jnp.float32)
+    f, g = p._loss_value_and_grad(shard)(x)
+
+    f1, g1 = p._loss_value_and_grad(shard, jnp.ones((n,), jnp.float32))(x)
+    np.testing.assert_allclose(f1, f, rtol=1e-6, err_msg=name)
+    np.testing.assert_allclose(g1, g, rtol=1e-6, err_msg=name)
+
+    pad = 5
+    padded = jax.tree_util.tree_map(
+        lambda a: np.pad(np.asarray(a),
+                         [(0, pad)] + [(0, 0)] * (np.ndim(a) - 1)), shard)
+    mask = jnp.asarray(np.r_[np.ones(n), np.zeros(pad)], jnp.float32)
+    fp, gp = p._loss_value_and_grad(
+        jax.tree_util.tree_map(jnp.asarray, padded), mask)(x)
+    np.testing.assert_allclose(fp, f, rtol=1e-6, err_msg=name)
+    np.testing.assert_allclose(gp, g, rtol=1e-6, err_msg=name)
+
+
 def test_end_to_end_four_workers_converges(named_problem):
     name, p = named_problem
     res = run(ExperimentSpec(
@@ -144,17 +172,6 @@ def test_register_decorator_plugin_roundtrip():
     finally:
         problems.unregister("_conformance_tmp")
     assert "_conformance_tmp" not in problems.available()
-
-
-def test_scheduler_deprecation_reexports():
-    """`from repro.runtime.scheduler import LogRegProblem` must keep
-    working and resolve to the moved classes."""
-    from repro.problems import LogRegProblem, WorkerProblem
-    from repro.runtime import scheduler
-    assert scheduler.LogRegProblem is LogRegProblem
-    assert scheduler.WorkerProblem is WorkerProblem
-    from repro.runtime import LogRegProblem as runtime_lrp
-    assert runtime_lrp is LogRegProblem
 
 
 def test_softmax_is_matrix_valued_on_the_wire():

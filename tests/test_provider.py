@@ -94,7 +94,7 @@ def test_scheduler_failure_respawns_are_cold():
     from repro.core.admm import AdmmOptions
     from repro.core.fista import FistaOptions
     from repro.runtime import Scheduler, SchedulerConfig
-    from repro.runtime.scheduler import LogRegProblem
+    from repro.problems import LogRegProblem
     prob = LogRegProblem(scaled(2048, 128, density=0.05, lam1=0.3),
                          fista=FistaOptions(min_iters=1, eps_grad=1e-3))
     sched = Scheduler(prob, SchedulerConfig(

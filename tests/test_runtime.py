@@ -8,9 +8,9 @@ import pytest
 from repro.configs.logreg_paper import scaled
 from repro.core.admm import AdmmOptions
 from repro.core.fista import FistaOptions
+from repro.problems import LogRegProblem
 from repro.runtime import PoolConfig, Scheduler, SchedulerConfig
 from repro.runtime.pool import LambdaPool, master_drain
-from repro.runtime.scheduler import LogRegProblem
 
 CFG = scaled(2048, 128, density=0.05, lam1=0.3)
 ADMM = AdmmOptions(max_iters=40)
